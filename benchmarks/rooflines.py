@@ -17,11 +17,12 @@ from ._util import csv_row
 
 def _kernel_rows(rows):
     from repro import rp
-    from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+    from repro.launch.roofline import TARGET_DEVICE_KIND, chip_peaks
+    peaks = chip_peaks(TARGET_DEVICE_KIND)
 
     def bound(name, cost, extra=""):
-        compute_s = cost.flops / PEAK_FLOPS
-        memory_s = cost.hbm_bytes / HBM_BW
+        compute_s = cost.flops / peaks.flops
+        memory_s = cost.hbm_bytes / peaks.hbm_bw
         serial_s = compute_s + memory_s
         pipelined_s = max(compute_s, memory_s)
         rows.append(csv_row(

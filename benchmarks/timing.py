@@ -415,11 +415,12 @@ def _perf_rows(rows, fast=True):
             rp.ProjectorSpec(family=family, k=k, dims=fdims, rank=rank),
             jax.random.fold_in(key, 4))
 
-        def fused(y, p, w, m, v, lr, c1, c2, op=op):
-            rp.count_kernel_dispatch()
-            with rp.force_pallas():
-                return fused_update_buckets(op, y, p, w, m, v, lr, c1, c2,
-                                            **hp)
+        interpret = rp.plan_update(op, nb, fused=True).interpret
+
+        def fused(y, p, w, m, v, lr, c1, c2, op=op, interpret=interpret):
+            rp.count_kernel_dispatch(interpret=interpret)
+            return fused_update_buckets(op, y, p, w, m, v, lr, c1, c2,
+                                        interpret=interpret, **hp)
 
         def unfused(y, p, w, m, v, lr, c1, c2, op=op):
             with rp.force_pallas():

@@ -162,7 +162,9 @@ class PytreeSketcher:
     def __init__(self, cfg: SketchConfig, example_tree: Any, *,
                  mesh=None, bucket_spec=None, constrain: bool = True):
         self.cfg = cfg
-        self.mesh = mesh
+        # runtime import: launch/mesh imports only jax
+        from repro.launch.mesh import auto_axes
+        self.mesh = auto_axes(mesh)
         self.bucket_spec = bucket_spec
         # constrain=False disables ALL bucket-layout constraints, including
         # the legacy global-settings hint — required inside shard_map bodies

@@ -10,7 +10,7 @@ which materializes the dense reconstruction g_hat in HBM and then streams
 every dense operand again for error feedback and the optimizer math. This
 module fuses the whole chain into ONE launch per leaf on the reconstruct
 sweep's own grid `(B/TB, d1/BA, k/TK)` (k-tile INNERMOST): each
-`(TB, BA, d2..dN)` tile accumulates its reconstruction across the k grid
+`(TB, BA*Q, L)` tile accumulates its reconstruction across the k grid
 axis in the revisited RESIDUAL output block — the same revisited-block
 accumulation as `_sweep._reconstruct_kernel`, with the residual output
 doubling as the g_hat accumulator — and the LAST k step runs the epilogue
@@ -30,7 +30,7 @@ the storage dtype on the way out — the same cast points as the unfused
 reference, so 'lean'-policy bf16 moments see identical rounding).
 
 `plan_fused_update` budgets the launch: a reconstruct-sweep plan whose
-VMEM budget additionally charges the eight dense `(TB, BA, d2..dN)` blocks
+VMEM budget additionally charges the eight dense `(TB, BA*Q, L)` blocks
 the fusion keeps resident (p/w/m/v in, resid/w'/m'/v' out).
 `fused_hbm_bytes` / `unfused_hbm_bytes` give the analytic HBM traffic of
 the two formulations for the SAME plan — the accounting behind the
@@ -49,35 +49,21 @@ from repro.core.cp_rp import CPRP
 from repro.core.formats import _prod
 from repro.core.tt_rp import TTRP
 
-from ._sweep import _core_specs, _imap
+from ._sweep import (_imap, compiler_params, core_specs, reconstruct_body,
+                     reconstruct_loads)
 from .ops import (MAX_ORDER, VMEM_BUDGET_BYTES, ContractionPlan, _pad_axis,
-                  _pad_operands, kernel_order_supported, plan_contraction,
-                  sweep_hbm_bytes, tt_cores_squeezed)
+                  dense_blocks_view, kernel_order_supported, plan_contraction,
+                  sweep_hbm_bytes, sweep_operands, tt_cores_squeezed)
 
 
 def plan_fused_update(family: str, k: int, b: int, dims: tuple[int, ...],
                       rank: int, *,
                       budget: int = VMEM_BUDGET_BYTES) -> ContractionPlan:
-    """Reconstruct-sweep plan for the fused launch.
-
-    Fixed point over `plan_contraction(kind='reconstruct')`: the fused
-    kernel keeps EIGHT dense `(TB, BA, d2..dN)` blocks resident on top of
-    the sweep's own buffers (four optimizer inputs, four outputs), and
-    those extra bytes depend on the tiles the budget chooses — iterate
-    until the tiling is stable under its own surcharge.
-    """
-    dims = tuple(int(d) for d in dims)
-    trail_elems = _prod(dims[1:])
-    plan = plan_contraction(family, "reconstruct", k, b, dims, rank,
-                            budget=budget)
-    for _ in range(16):
-        extra = 8 * 4 * plan.tb * plan.ba * trail_elems
-        new = plan_contraction(family, "reconstruct", k, b, dims, rank,
-                               budget=max(1, budget - extra))
-        if (new.tk, new.tb, new.ba) == (plan.tk, plan.tb, plan.ba):
-            return new
-        plan = new
-    return plan
+    """Reconstruct-sweep plan for the fused launch: the planner also
+    charges the EIGHT dense `(TB, BA*Q, L)` blocks the fusion keeps
+    resident (four optimizer inputs, four outputs)."""
+    return plan_contraction(family, "reconstruct", k, b, dims, rank,
+                            budget=budget, dense_blocks=8)
 
 
 def fused_hbm_bytes(plan: ContractionPlan) -> int:
@@ -110,19 +96,10 @@ def _fused_kernel(y_ref, s_ref, *refs, steps, n_core, scale, b1, b2, eps,
     core_refs = refs[:n_core]
     p_ref, w_ref, m_ref, v_ref = refs[n_core:n_core + 4]
     r_ref, wo_ref, mo_ref, vo_ref = refs[n_core + 4:]
-    m_steps, h_spec, out_spec = steps
     ik = pl.program_id(2)
-    # one reconstruct k-step, verbatim from _sweep._reconstruct_kernel
-    mm = core_refs[-1][...]
-    if m_steps[0] is not None:           # CP layout transpose; None for TT
-        mm = jnp.einsum(m_steps[0], mm)
-    for spec, g_ref in zip(m_steps[1:], reversed(core_refs[1:-1])):
-        mm = jnp.einsum(spec, g_ref[...], mm,
-                        preferred_element_type=jnp.float32)
-    h = jnp.einsum(h_spec, y_ref[...], core_refs[0][...],
-                   preferred_element_type=jnp.float32)
-    out = jnp.einsum(out_spec, h, mm,
-                     preferred_element_type=jnp.float32) * scale
+    # one reconstruct k-step: the program of _sweep._reconstruct_kernel
+    out = reconstruct_body(y_ref[...], reconstruct_loads(core_refs), steps)
+    out = out.reshape(r_ref.shape) * scale
 
     @pl.when(ik == 0)
     def _init():
@@ -149,38 +126,40 @@ def _fused_kernel(y_ref, s_ref, *refs, steps, n_core, scale, b1, b2, eps,
         vo_ref[...] = v32
 
 
-@functools.partial(jax.jit, static_argnames=("steps", "trail", "tk", "tb",
-                                             "ba", "scale", "b1", "b2",
-                                             "eps", "wd", "interpret"))
-def _fused_launch(y, s, *arrs, steps, trail, tk, tb, ba, scale, b1, b2,
-                  eps, wd, interpret):
+@functools.partial(jax.jit, static_argnames=("steps", "tk", "tb", "ba",
+                                             "scale", "b1", "b2", "eps",
+                                             "wd", "interpret"))
+def _fused_launch(y, s, *arrs, steps, tk, tb, ba, scale, b1, b2, eps, wd,
+                  interpret):
     cores, dense = arrs[:-4], arrs[-4:]
     b, k = y.shape
-    d1 = cores[0].shape[1]
+    _, rows, ell = dense[0].shape
+    d1 = cores[0].shape[0]
     assert k % tk == 0 and b % tb == 0 and d1 % ba == 0, (k, tk, b, tb, d1, ba)
-    grid = (b // tb, d1 // ba, k // tk)
-    dense_spec = pl.BlockSpec((tb, ba) + trail,
-                              _imap(0, 1, *([None] * len(trail))))
+    dense_spec = pl.BlockSpec((tb, ba * (rows // d1), ell),
+                              _imap(0, 1, None))
     in_specs = [pl.BlockSpec((tb, tk), _imap(0, 2)),
                 pl.BlockSpec((1, 4), _imap(None, None))]
-    in_specs += _core_specs(cores, tk, ba, lead_pos=1, k_pos=2)
+    in_specs += core_specs(cores, "reconstruct", tk=tk, ba=ba, k_pos=2,
+                           lead_pos=1)
     in_specs += [dense_spec] * 4
-    blk = jax.ShapeDtypeStruct((b, d1) + trail, jnp.float32)
+    blk = jax.ShapeDtypeStruct(dense[0].shape, jnp.float32)
     return pl.pallas_call(
         functools.partial(_fused_kernel, steps=steps, n_core=len(cores),
                           scale=scale, b1=b1, b2=b2, eps=eps, wd=wd,
                           nk=k // tk),
-        grid=grid,
+        grid=(b // tb, d1 // ba, k // tk),
         in_specs=in_specs,
         out_specs=(dense_spec,) * 4,
         out_shape=(blk,) * 4,
+        compiler_params=compiler_params(),
         interpret=interpret,
     )(y, s, *arrs)
 
 
 def fused_update_buckets(op, y, p, w, m, v, lr, c1, c2, *, alpha: float,
                          b1: float, b2: float, eps: float,
-                         weight_decay: float, interpret: bool = True):
+                         weight_decay: float, interpret: bool):
     """ONE launch: unsketch + error feedback + AdamW for one leaf's buckets.
 
     op     : a TT/CP operator at a kernel-supported order (the one the
@@ -208,20 +187,18 @@ def fused_update_buckets(op, y, p, w, m, v, lr, c1, c2, *, alpha: float,
     nb = y.shape[0]
     dims = tuple(op.in_dims)
     plan = plan_fused_update(family, op.k, nb, dims, op.rank)
-    yk = _pad_axis(_pad_axis(y, 0, plan.tb), 1, plan.tk)
-    dense = [_pad_axis(_pad_axis(a, 0, plan.tb), 1, plan.ba)
-             for a in (p, w, m, v)]
+    yk = _pad_axis(_pad_axis(y.astype(jnp.float32), 0, plan.tb), 1, plan.tk)
+    dense = [dense_blocks_view(plan, a) for a in (p, w, m, v)]
     scal = jnp.stack([jnp.asarray(lr, jnp.float32),
                       jnp.asarray(c1, jnp.float32),
                       jnp.asarray(c2, jnp.float32),
                       jnp.zeros((), jnp.float32)]).reshape(1, 4)
-    out = _fused_launch(yk, scal, *_pad_operands(plan, cores), *dense,
-                        steps=plan.steps, trail=dims[1:], tk=plan.tk,
-                        tb=plan.tb, ba=plan.ba,
-                        scale=float(alpha) / math.sqrt(op.k),
+    out = _fused_launch(yk, scal, *sweep_operands(family, cores, plan),
+                        *dense, steps=plan.steps, tk=plan.tk, tb=plan.tb,
+                        ba=plan.ba, scale=float(alpha) / math.sqrt(op.k),
                         b1=float(b1), b2=float(b2), eps=float(eps),
                         wd=float(weight_decay), interpret=interpret)
-    return tuple(o[:nb, :dims[0]] for o in out)
+    return tuple(o[:nb].reshape((nb,) + dims) for o in out)
 
 
 __all__ = ["fused_hbm_bytes", "fused_update_buckets", "plan_fused_update",

@@ -8,7 +8,8 @@ or TT format" — this package is that regime's hot path. All FOUR
 carry-sweep schedule at any order 2..MAX_ORDER, batched over the inputs in
 ONE launch (replacing the retired order-3-only, unbatched `tt_dot`):
 
-  plan.py  — `plan_carry_sweep` / `CarryPlan`: the einsum carry program +
+  plan.py  — `plan_carry_sweep` / `CarryPlan`: the carry program (one
+             matmul-and-multiply step per mode) + block-aligned,
              VMEM-budgeted (tk, tb) tiles + the (k-outermost, batch) grid.
   carry.py — the Pallas kernel executing the program verbatim.
   ref.py   — order-generic batched einsum oracles (also the XLA path).

@@ -1,40 +1,37 @@
 """Pallas TPU kernel: batched structured-input carry-sweep projection.
 
-Executes the einsum carry program emitted by `plan.plan_carry_sweep`
-verbatim, for all four (operator, input) family pairings at any static
-order 2..MAX_ORDER — the compressed-domain replacement for the retired
-order-3-only `tt_dot` kernel.
+Executes the carry program emitted by `plan.plan_carry_sweep` verbatim, for
+all four (operator, input) family pairings at any static order
+2..MAX_ORDER.
 
 Schedule:
 
-* grid = (k/TK, B/TB) — k-tile OUTERMOST: the operator cores' block index
-  depends only on ik, so one k-tile's cores are fetched once and stay
+* grid = (k/TK, B/TB) — k-tile OUTERMOST: the operator slabs' block index
+  depends only on ik, so one k-tile's operator is fetched once and stays
   VMEM-resident while every batch tile of structured inputs streams
-  through them (the same core-residency argument as the dense projection
-  sweep, with the batch of inputs taking the place of the dense bucket
-  stream). The input cores' index depends only on ib.
-* No accumulation axis: unlike the dense sweep there is no d1 grid axis —
-  every mode is contracted in full inside the instance, carrying the
-  (TB, TK, R_op·R_in) bond state between steps — so each (TB, TK) output
-  block is written exactly once.
-* TK=128 puts k on the lane axis; every carry step is then a TK-batched
-  small contraction (MXU for the bond updates, VPU for the CPxCP
-  Hadamard). The JLT 1/sqrt(k) scaling is FUSED into the epilogue.
+  through it. The input slabs' index depends only on ib.
+* No accumulation axis: every mode is contracted in full inside the
+  instance, carrying the `(R_op, R_in, TB, TK)` bond state between modes,
+  so each (TB, TK) output block is written exactly once.
+* The batch sits on the sublanes and k on the lanes. A mode update is one
+  2-D MXU matmul per (operator source bond, input source bond) — input
+  slab `(in_dst*TB, d)` @ operator slab `(d, op_dst*TK)` — then aligned
+  block slices of the result multiply the carry elementwise. The JLT
+  1/sqrt(k) scaling is FUSED into the epilogue.
 
-Padding contract (enforced by `ops.struct_project`): the k axis of every
-operator core is zero-padded to TK (zero rows project to zero and are
-sliced away), the batch axis of every input core to TB (zero cores
-contribute zero rows). Bond/mode axes are never tiled.
+Operand layouts (built by `ops.struct_project`, see `carry_operands`): the
+operator at mode n as `(K/TK, op_src, d, op_dst*TK)`, the input as
+`(B/TB, in_src, in_dst*TB, d)` — both pre-tiled along their streamed axis,
+so every block is whole in its last two dims. A "diag" coupling (an
+interior CP factor) stacks all components into one slab with source count
+1. Zero padding of k, the batch, and the bonds is inert.
 
 `carry_sweep_project_pipelined` is the DOUBLE-BUFFERED variant (plan
 `pipeline='double'`): grid = (k/TK,) with the batch axis swept by an
-in-kernel fori_loop — the per-batch-tile input cores are prefetched into a
+in-kernel fori_loop — the per-batch-tile input slabs are prefetched into a
 second VMEM slot with explicit `pltpu.make_async_copy` DMAs while the
-current batch tile's carry program runs, so input transfers overlap the
-bond updates. Operator cores keep their BlockSpec residency per k-tile;
-the `(B, TK)` output block is written one batch tile at a time. The
-planner accounts the second input slot and the full-batch output block
-(`plan.plan_carry_sweep(pipeline='double')`).
+current batch tile's carry program runs, and the `(B, TK)` output block is
+written one batch tile at a time.
 """
 from __future__ import annotations
 
@@ -45,83 +42,79 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _carry_kernel(*refs, program, n_op, scale):
-    op_refs = refs[:n_op]
-    x_refs = refs[n_op:-1]
-    o_ref = refs[-1]
-    env = {}
-
-    def operand(name):
-        if name in env:                       # 'c' or 't'
-            return env[name]
-        idx = int(name[1:])
-        return (op_refs[idx] if name[0] == "g" else x_refs[idx])[...]
-
-    for dst, spec, a, b in program:
-        env[dst] = jnp.einsum(spec, operand(a), operand(b),
-                              preferred_element_type=jnp.float32)
-    o_ref[...] = env["c"] * scale             # (TB, TK)
+from .._sweep import compiler_params, dot
 
 
-def _imap2(*pattern):
-    """Index map over the 2-axis (ik, ib) grid: select an axis by position
-    or pin 0 (`None`) — the block stays put along that operand axis."""
-    def f(i0, i1):
-        prog = (i0, i1)
-        return tuple(prog[p] if p is not None else 0 for p in pattern)
-    return f
+def carry_body(program, g_load, x_load, tb: int, tk: int):
+    """Run the carry program; `g_load(n, u)` is the `(d, op_dst*TK)`
+    operator slab of source bond u at mode n, `x_load(n, e)` the
+    `(in_dst*TB, d)` input slab of source bond e. Returns `(TB, TK)`."""
+    carry = None                       # carry[v]: (R_in, TB, TK)
+    for n, (_, oc, o_src, o_dst, ic, i_src, i_dst) in enumerate(program):
+        new = [None] * o_dst
+        for u in range(o_src if oc == "full" else 1):
+            g = g_load(n, u)
+            for e in range(i_src if ic == "full" else 1):
+                p = dot(x_load(n, e), g).reshape(i_dst, tb, o_dst * tk)
+                for v in range(o_dst):
+                    term = p[:, :, v * tk:(v + 1) * tk]
+                    if carry is not None:
+                        c = carry[u if oc == "full" else v]
+                        term = term * (c[e][None] if ic == "full" else c)
+                    new[v] = term if new[v] is None else new[v] + term
+        carry = new
+    return carry[0][0]
 
 
-@functools.partial(jax.jit, static_argnames=("n_op", "program", "tk", "tb",
+def _carry_kernel(*refs, program, scale, tb, tk):
+    n = len(program)
+    g_refs, x_refs, o_ref = refs[:n], refs[n:2 * n], refs[-1]
+    y = carry_body(program, lambda m, u: g_refs[m][0, u],
+                   lambda m, e: x_refs[m][0, e], tb, tk)
+    o_ref[...] = y * scale
+
+
+@functools.partial(jax.jit, static_argnames=("program", "tk", "tb",
                                              "scale", "interpret"))
-def carry_sweep_project(*cores: jnp.ndarray, n_op: int, program,
-                        tk: int, tb: int, scale: float,
-                        interpret: bool) -> jnp.ndarray:
+def carry_sweep_project(*operands: jnp.ndarray, program, tk: int, tb: int,
+                        scale: float, interpret: bool) -> jnp.ndarray:
     """ONE launch projecting a whole batch of structured inputs.
 
-    cores = (*op_cores, *in_cores): op cores lead with the (padded) k axis,
-    input cores lead with the (padded) batch axis; `n_op` splits the two
-    groups. Requires k % tk == 0 and B % tb == 0. Returns (B, k) float32.
+    operands = (*op_slabs, *in_slabs), one of each per mode (see the module
+    docstring). Returns the (B, K) float32 sketch, B and K padded.
     """
-    op_cores, in_cores = cores[:n_op], cores[n_op:]
-    k = op_cores[0].shape[0]
-    b = in_cores[0].shape[0]
-    assert len(op_cores) == len(in_cores), (len(op_cores), len(in_cores))
-    assert k % tk == 0 and b % tb == 0, (k, tk, b, tb)
-    grid = (k // tk, b // tb)
-    in_specs = [pl.BlockSpec((tk,) + g.shape[1:],
-                             _imap2(0, *([None] * (g.ndim - 1))))
-                for g in op_cores]
-    in_specs += [pl.BlockSpec((tb,) + x.shape[1:],
-                              _imap2(1, *([None] * (x.ndim - 1))))
-                 for x in in_cores]
+    n = len(program)
+    g, x = operands[:n], operands[n:]
+    nk, nb = g[0].shape[0], x[0].shape[0]
+    in_specs = [pl.BlockSpec((1,) + a.shape[1:],
+                             lambda ik, ib: (ik, 0, 0, 0)) for a in g]
+    in_specs += [pl.BlockSpec((1,) + a.shape[1:],
+                              lambda ik, ib: (ib, 0, 0, 0)) for a in x]
     return pl.pallas_call(
-        functools.partial(_carry_kernel, program=program, n_op=n_op,
-                          scale=scale),
-        grid=grid,
+        functools.partial(_carry_kernel, program=program, scale=scale,
+                          tb=tb, tk=tk),
+        grid=(nk, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((tb, tk), _imap2(1, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, k), jnp.float32),
+        out_specs=pl.BlockSpec((tb, tk), lambda ik, ib: (ib, ik)),
+        out_shape=jax.ShapeDtypeStruct((nb * tb, nk * tk), jnp.float32),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(*cores)
+    )(*operands)
 
 
-def _carry_pipelined_kernel(*refs, program, n_op, scale, nb, tb, in_shapes):
-    op_refs = refs[:n_op]
-    x_hbm = refs[n_op:-1]                 # full input cores, manual DMA
-    o_ref = refs[-1]                      # (B, TK) block for this k-tile
+def _carry_pipelined_kernel(*refs, program, scale, nb, tb, tk):
+    n = len(program)
+    g_refs, x_hbm, o_ref = refs[:n], refs[n:2 * n], refs[-1]
 
     def body(sems, **bufs):
-        xs = [bufs[f"x{j}"] for j in range(len(x_hbm))]
+        xs = [bufs[f"x{m}"] for m in range(n)]
 
-        def dma(j, slot, i):
-            return pltpu.make_async_copy(
-                x_hbm[j].at[pl.ds(i * tb, tb)], xs[j].at[slot],
-                sems.at[j, slot])
+        def dma(m, slot, i):
+            return pltpu.make_async_copy(x_hbm[m].at[i], xs[m].at[slot],
+                                         sems.at[m, slot])
 
-        for j in range(len(x_hbm)):       # warm-up: batch tile 0, slot 0
-            dma(j, 0, 0).start()
+        for m in range(n):                # warm-up: batch tile 0, slot 0
+            dma(m, 0, 0).start()
 
         def step(i, carry):
             slot = jax.lax.rem(i, 2)
@@ -129,71 +122,45 @@ def _carry_pipelined_kernel(*refs, program, n_op, scale, nb, tb, in_shapes):
 
             @pl.when(i + 1 < nb)
             def _prefetch():              # next tile streams during compute
-                for j in range(len(x_hbm)):
-                    dma(j, nxt, i + 1).start()
+                for m in range(n):
+                    dma(m, nxt, i + 1).start()
 
-            for j in range(len(x_hbm)):
-                dma(j, slot, i).wait()
-            env = {}
-
-            def operand(name):
-                if name in env:           # 'c' or 't'
-                    return env[name]
-                idx = int(name[1:])
-                return (op_refs[idx][...] if name[0] == "g"
-                        else xs[idx][slot])
-
-            for dst, spec, a, b in program:
-                env[dst] = jnp.einsum(spec, operand(a), operand(b),
-                                      preferred_element_type=jnp.float32)
-            o_ref[pl.ds(i * tb, tb), :] = env["c"] * scale
+            for m in range(n):
+                dma(m, slot, i).wait()
+            y = carry_body(program, lambda m, u: g_refs[m][0, u],
+                           lambda m, e: xs[m][slot, e], tb, tk)
+            o_ref[pl.ds(pl.multiple_of(i * tb, tb), tb), :] = y * scale
             return carry
 
         jax.lax.fori_loop(0, nb, step, 0)
 
     pl.run_scoped(body,
-                  sems=pltpu.SemaphoreType.DMA((len(x_hbm), 2)),
-                  **{f"x{j}": pltpu.VMEM((2, tb) + shp, jnp.float32)
-                     for j, shp in enumerate(in_shapes)})
+                  sems=pltpu.SemaphoreType.DMA((n, 2)),
+                  **{f"x{m}": pltpu.VMEM((2,) + x_hbm[m].shape[1:],
+                                         jnp.float32) for m in range(n)})
 
 
-@functools.partial(jax.jit, static_argnames=("n_op", "program", "tk", "tb",
+@functools.partial(jax.jit, static_argnames=("program", "tk", "tb",
                                              "scale", "interpret"))
-def carry_sweep_project_pipelined(*cores: jnp.ndarray, n_op: int, program,
-                                  tk: int, tb: int, scale: float,
+def carry_sweep_project_pipelined(*operands: jnp.ndarray, program, tk: int,
+                                  tb: int, scale: float,
                                   interpret: bool) -> jnp.ndarray:
-    """Double-buffered carry sweep: same contraction, overlapped streams.
-
-    Identical contract to `carry_sweep_project`, laid out as grid = (k/TK,)
-    with the batch axis swept by an in-kernel fori_loop: the input cores
-    live in `memory_space=ANY` and are double-buffered into VMEM scratch
-    by explicit DMAs, prefetching batch tile i+1 while tile i's carry
-    program runs against the k-tile-resident operator cores.
-    """
-    op_cores, in_cores = cores[:n_op], cores[n_op:]
-    k = op_cores[0].shape[0]
-    b = in_cores[0].shape[0]
-    assert len(op_cores) == len(in_cores), (len(op_cores), len(in_cores))
-    assert k % tk == 0 and b % tb == 0, (k, tk, b, tb)
-    in_specs = [pl.BlockSpec((tk,) + g.shape[1:],
-                             _imap1(0, *([None] * (g.ndim - 1))))
-                for g in op_cores]
-    in_specs += [pl.BlockSpec(memory_space=pltpu.ANY) for _ in in_cores]
+    """Double-buffered carry sweep: same contract as `carry_sweep_project`,
+    laid out as grid = (k/TK,) with the batch axis swept by an in-kernel
+    fori_loop over input slabs held in `memory_space=ANY`."""
+    n = len(program)
+    g, x = operands[:n], operands[n:]
+    nk, nb = g[0].shape[0], x[0].shape[0]
+    in_specs = [pl.BlockSpec((1,) + a.shape[1:], lambda ik: (ik, 0, 0, 0))
+                for a in g]
+    in_specs += [pl.BlockSpec(memory_space=pltpu.ANY) for _ in x]
     return pl.pallas_call(
         functools.partial(_carry_pipelined_kernel, program=program,
-                          n_op=n_op, scale=scale, nb=b // tb, tb=tb,
-                          in_shapes=tuple(x.shape[1:] for x in in_cores)),
-        grid=(k // tk,),
+                          scale=scale, nb=nb, tb=tb, tk=tk),
+        grid=(nk,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((b, tk), _imap1(None, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, k), jnp.float32),
+        out_specs=pl.BlockSpec((nb * tb, tk), lambda ik: (0, ik)),
+        out_shape=jax.ShapeDtypeStruct((nb * tb, nk * tk), jnp.float32),
+        compiler_params=compiler_params(),
         interpret=interpret,
-    )(*cores)
-
-
-def _imap1(*pattern):
-    """Index map over the 1-axis (ik,) pipelined grid."""
-    def f(i0):
-        prog = (i0,)
-        return tuple(prog[p] if p is not None else 0 for p in pattern)
-    return f
+    )(*operands)

@@ -10,9 +10,9 @@ order 2..MAX_ORDER. The wrapper:
   * converts to the kernel layouts (squeezed TT boundary bonds on both the
     operator and the input; CP weights folded into the first factor — a
     scalar reweighting of one factor, exact by multilinearity),
-  * pads the operator's k axis to the k tile and the input's batch axis to
-    the batch tile (zero rows/items are inert and sliced away),
-  * plans the sweep (`plan.plan_carry_sweep`) and launches
+  * plans the sweep (`plan.plan_carry_sweep`), lays every mode out as the
+    kernel's pre-tiled slabs (`carry_operands`; zero padding of k, the
+    batch and the bonds is inert and sliced away), and launches
     `carry.carry_sweep_project` with the fused 1/sqrt(k) epilogue.
 
 With `use_kernel=False` (or for orders outside kernel support) the same
@@ -32,10 +32,10 @@ from repro.core.formats import (STRUCT_TYPES, BatchedCPTensor,
                                 BatchedTTTensor, CPTensor, TTTensor)
 from repro.core.tt_rp import TTRP
 
-from ..ops import _pad_axis, kernel_order_supported, tt_cores_squeezed
+from ..ops import LANES, _pad_axis, kernel_order_supported, tt_cores_squeezed
 from . import ref
 from .carry import carry_sweep_project, carry_sweep_project_pipelined
-from .plan import plan_carry_sweep
+from .plan import CarryPlan, plan_carry_sweep
 
 
 def _as_batched(x):
@@ -76,16 +76,75 @@ def struct_rank(x) -> int:
     return x.rank
 
 
-def struct_project(op, x, *, interpret: bool = True,
-                   use_kernel: bool = True,
+def _mode_tensors(family: str, cores, rank: int) -> list:
+    """Per-mode `(rows, src, d, dst)` tensors of a squeezed core list — the
+    first mode fans out from the unit bond, the last fans in — with every
+    bond zero-padded to `rank`. An interior CP factor stays `(rows, d, R)`
+    (its "diag" coupling)."""
+    n = len(cores)
+    out = []
+    for m, c in enumerate(cores):
+        if m == 0:
+            t = c[:, None, :, :]
+        elif m == n - 1:
+            t = (c if family == "tt" else c.transpose(0, 2, 1))[..., None]
+        elif family == "tt":
+            t = c
+        else:
+            out.append(_pad_axis(c, 2, rank))
+            continue
+        t = _pad_axis(t, 1, rank) if m else t
+        out.append(_pad_axis(t, 3, rank) if m < n - 1 else t)
+    return out
+
+
+def _op_slab(t: jnp.ndarray, tk: int) -> jnp.ndarray:
+    """(K, src, d, dst) -> (K/TK, src, d, dst*TK); diag (K, d, R) ->
+    (K/TK, 1, d, R*TK)."""
+    t = _pad_axis(t, 0, tk)
+    nk = t.shape[0] // tk
+    if t.ndim == 3:
+        t = t.reshape(nk, tk, *t.shape[1:]).transpose(0, 2, 3, 1)
+        return t.reshape(nk, 1, t.shape[1], -1)
+    t = t.reshape(nk, tk, *t.shape[1:]).transpose(0, 2, 3, 4, 1)
+    return t.reshape(nk, t.shape[1], t.shape[2], -1)
+
+
+def _in_slab(t: jnp.ndarray, tb: int) -> jnp.ndarray:
+    """(B, src, d, dst) -> (B/TB, src, dst*TB, d); diag (B, d, R) ->
+    (B/TB, 1, R*TB, d)."""
+    t = _pad_axis(t.astype(jnp.float32), 0, tb)
+    nb = t.shape[0] // tb
+    if t.ndim == 3:
+        t = t.reshape(nb, tb, *t.shape[1:]).transpose(0, 3, 1, 2)
+        return t.reshape(nb, 1, -1, t.shape[-1])
+    t = t.reshape(nb, tb, *t.shape[1:]).transpose(0, 2, 4, 1, 3)
+    return t.reshape(nb, t.shape[1], -1, t.shape[-1])
+
+
+def carry_operands(plan: CarryPlan, op_cores, in_cores) -> list:
+    """The kernel's operands for `plan`: one operator slab per mode, then
+    one input slab per mode (see `carry.py`)."""
+    ops = [_op_slab(t, plan.tk) for t in
+           _mode_tensors(plan.op_family, op_cores, plan.r_op)]
+    ins = [_in_slab(t, plan.tb) for t in
+           _mode_tensors(plan.in_family, in_cores, plan.r_in)]
+    if plan.pipeline == "double":
+        # the double-buffered input slots are sliced per DMA, which Mosaic
+        # allows only on lane-aligned slabs: zero-pad each mode d (inert)
+        ops = [_pad_axis(g, 2, LANES) for g in ops]
+        ins = [_pad_axis(x, 3, LANES) for x in ins]
+    return ops + ins
+
+
+def struct_project(op, x, *, interpret: bool, use_kernel: bool = True,
                    pipeline: str = "serial") -> jnp.ndarray:
     """Project structured input(s) with a TT/CP operator, never densifying.
 
     x: TTTensor / CPTensor -> (k,); BatchedTTTensor / BatchedCPTensor with
     batch B -> (B, k) — ONE carry-sweep launch for the whole batch.
     `pipeline='double'` selects the double-buffered carry sweep
-    (`carry.carry_sweep_project_pipelined`); same result bitwise intent,
-    fp32-tolerance equivalent in practice.
+    (`carry.carry_sweep_project_pipelined`); same result.
     """
     if not isinstance(op, (TTRP, CPRP)):
         raise TypeError(f"struct_project needs a TT/CP operator, got "
@@ -108,15 +167,14 @@ def struct_project(op, x, *, interpret: bool = True,
         return y if batched else y[0]
     plan = plan_carry_sweep(op_family, in_family, k, b, op.in_dims,
                             op.rank, struct_rank(xb), pipeline=pipeline)
-    op_pad = tuple(_pad_axis(g, 0, plan.tk) for g in op_cores)
-    in_pad = tuple(_pad_axis(c, 0, plan.tb) for c in in_cores)
     kernel = (carry_sweep_project_pipelined if plan.pipeline == "double"
               else carry_sweep_project)
-    y = kernel(*op_pad, *in_pad, n_op=len(op_pad),
+    y = kernel(*carry_operands(plan, op_cores, in_cores),
                program=plan.program, tk=plan.tk, tb=plan.tb,
                scale=1.0 / math.sqrt(k), interpret=interpret)
     y = y[:b, :k]
     return y if batched else y[0]
 
 
-__all__ = ["STRUCT_TYPES", "struct_project", "struct_rank"]
+__all__ = ["STRUCT_TYPES", "carry_operands", "struct_project",
+           "struct_rank"]

@@ -4,37 +4,41 @@ This is the structured-input counterpart of `repro.kernels.ops.plan_contraction`
 (which plans the DENSE-input mode sweep): instead of streaming a dense
 `(B, d1..dN)` block and peeling one mode per step, the carry sweep contracts
 one mode of the OPERATOR against the same mode of the INPUT's compressed
-representation, carrying a small `(TB, TK, R_op·R_in)` bond state between
-steps — the paper's "project without ever densifying" formulation
+representation, carrying a small `(R_op, R_in, TB, TK)` bond state between
+modes — the paper's "project without ever densifying" formulation
 (Sec. 4.1; Feng et al.'s TT-input carry sweep; Iwen et al.'s modewise maps
 on compressed inputs). Cost is O(k N d R R~ (R + R~)) per item instead of
 the dense path's O(k R d^N) (`repro.core.theory.flops_project_struct`).
 
-All FOUR structured pairings share one program shape — a flat tuple of
-two-operand einsum steps `(dst, spec, src_a, src_b)` with sources in
-{'c' (carry), 't' (temp), 'g<n>' (operator core/factor n), 'x<n>' (input
-core/factor n)} — emitted by `_carry_program` for any static order
-2 <= N <= `MAX_ORDER`:
+All FOUR pairings share one program shape, one step per mode, emitted by
+`_carry_program` for any static order 2 <= N <= `MAX_ORDER`:
 
-  op   input  per-mode carry update                       carry axes
-  tt x tt     c,g -> t;  t,x -> c                          (b, k, R, R~)
-  tt x cp     c,g -> t;  t,a -> c                          (b, k, R, R~)
-  cp x tt     c,x -> t;  t,f -> c                          (b, k, R, R~)
-  cp x cp     f,a -> t;  c * t (Hadamard on the bond)      (b, k, R, R~)
+  ("mode", op_coupling, op_src, op_dst, in_coupling, in_src, in_dst)
 
-The program is static (strings), so it participates in the jit cache key
-and each (op_family, in_family, order, tiling) compiles exactly once.
-`plan_carry_sweep` additionally budgets VMEM — operator cores per k-tile,
-input cores per batch-tile, the carry/temp peak, and the `(TB, TK)` output
-block — and shrinks the batch tile first (TK=128 keeps k on the lane axis),
-then the k tile, mirroring the dense project planner.
+A TT core couples every incoming bond to every outgoing one ("full"); an
+interior CP factor keeps component r on component r ("diag"); the first
+mode fans the unit bond out and the last fans in to it, for both families.
+Inside the kernel the batch sits on the sublanes and k on the lanes, and a
+mode update is one 2-D MXU matmul per (operator source bond, input source
+bond) — the input slab `(in_dst*TB, d)` against the operator slab
+`(d, op_dst*TK)`, contracting the mode d — followed by aligned block
+slices and elementwise products with the carry (a Hadamard on the bond for
+"diag"). So every step is a matmul with one contracting dimension or
+elementwise work.
+
+`plan_carry_sweep` budgets VMEM — operator slabs per k-tile and input slabs
+per batch tile (both double-buffered by the Pallas pipeline), the carry,
+and the matmul result — and shrinks the batch tile (eight sublanes at the
+floor) until it fits, raising `KernelPlanError` when it cannot, or when the
+ranks would unroll more than `MAX_UNROLLED_TERMS` bond updates.
 """
 from __future__ import annotations
 
 import dataclasses
 
-from ..ops import (MAX_ORDER, VMEM_BUDGET_BYTES, _lane_tile, _pow2_at_most,
-                   validate_pipeline)
+from ..ops import (MAX_ORDER, MAX_UNROLLED_TERMS, SUBLANES,
+                   VMEM_BUDGET_BYTES, KernelPlanError, _batch_tile,
+                   _lane_tile, validate_pipeline)
 
 _FAMILIES = ("tt", "cp")
 
@@ -44,62 +48,42 @@ def _require_family(name: str, value: str) -> None:
         raise ValueError(f"unknown {name} {value!r}; expected {_FAMILIES}")
 
 
-def _carry_program(op_family: str, in_family: str, order: int) -> tuple:
-    """The einsum carry program for one (operator, input) family pairing.
+def _bonds(family: str, n: int, order: int, rank: int) -> tuple:
+    """(coupling, src, dst) of `family`'s core at mode n."""
+    if n == 0:
+        return ("full", 1, rank)
+    if n == order - 1:
+        return ("full", rank, 1)
+    return ("full" if family == "tt" else "diag", rank, rank)
 
-    Step letters are local to each spec: b batch, k sketch row, d the mode
-    being contracted, u/v the operator TT bond (in/out), e/f the input TT
-    bond (in/out), r the operator CP component, p the input CP component.
-    Operator operands use the squeezed kernel layouts
-    (`ops.tt_cores_squeezed` / `op.factors`); input operands the squeezed
-    batched layouts (TT: (B, d1, R~), (B, R~, d, R~), (B, R~, dN); CP:
-    (B, d, R~) with weights folded into factor 0).
-    """
+
+def _carry_program(op_family: str, in_family: str, order: int,
+                   r_op: int, r_in: int) -> tuple:
+    """The carry program for one (operator, input) family pairing: one
+    ("mode", op_coupling, op_src, op_dst, in_coupling, in_src, in_dst)
+    step per mode, first mode first."""
     _require_family("operator family", op_family)
     _require_family("input family", in_family)
     if not 2 <= order <= MAX_ORDER:
         raise ValueError(
             f"carry-sweep kernels need 2 <= order <= {MAX_ORDER}, "
             f"got {order}")
-    steps: list[tuple] = []
-    last = order - 1
-    if op_family == "tt" and in_family == "tt":
-        steps.append(("c", "kdu,bde->bkue", "g0", "x0"))
-        for n in range(1, last):
-            steps.append(("t", "bkue,kudv->bkedv", "c", f"g{n}"))
-            steps.append(("c", "bkedv,bedf->bkvf", "t", f"x{n}"))
-        steps.append(("t", "bkue,kud->bked", "c", f"g{last}"))
-        steps.append(("c", "bked,bed->bk", "t", f"x{last}"))
-    elif op_family == "tt" and in_family == "cp":
-        steps.append(("c", "kdu,bdp->bkup", "g0", "x0"))
-        for n in range(1, last):
-            steps.append(("t", "bkup,kudv->bkpdv", "c", f"g{n}"))
-            steps.append(("c", "bkpdv,bdp->bkvp", "t", f"x{n}"))
-        steps.append(("t", "bkup,kud->bkpd", "c", f"g{last}"))
-        steps.append(("c", "bkpd,bdp->bk", "t", f"x{last}"))
-    elif op_family == "cp" and in_family == "tt":
-        steps.append(("c", "kdr,bde->bkre", "g0", "x0"))
-        for n in range(1, last):
-            steps.append(("t", "bkre,bedf->bkrdf", "c", f"x{n}"))
-            steps.append(("c", "bkrdf,kdr->bkrf", "t", f"g{n}"))
-        steps.append(("t", "bkre,bed->bkrd", "c", f"x{last}"))
-        steps.append(("c", "bkrd,kdr->bk", "t", f"g{last}"))
-    else:  # cp x cp: per-mode Hadamard on the (r, p) bond
-        steps.append(("c", "kdr,bdp->bkrp", "g0", "x0"))
-        for n in range(1, last):
-            steps.append(("t", "kdr,bdp->bkrp", f"g{n}", f"x{n}"))
-            steps.append(("c", "bkrp,bkrp->bkrp", "c", "t"))
-        steps.append(("t", "kdr,bdp->bkrp", f"g{last}", f"x{last}"))
-        steps.append(("c", "bkrp,bkrp->bk", "c", "t"))
-    return tuple(steps)
+    return tuple(("mode",) + _bonds(op_family, n, order, r_op)
+                 + _bonds(in_family, n, order, r_in) for n in range(order))
+
+
+def _unrolled_terms(program) -> int:
+    """Elementwise bond updates the kernel body unrolls."""
+    return sum((s[2] if s[1] == "full" else 1)
+               * (s[5] if s[4] == "full" else 1) * s[3] for s in program)
 
 
 @dataclasses.dataclass(frozen=True)
 class CarryPlan:
     """A fully-resolved carry-sweep schedule for one structured launch.
 
-    `program` is the static einsum step tuple (`_carry_program`) the kernel
-    in `carry.py` executes verbatim. `vmem_bytes` is the accounted
+    `program` is the static step tuple (`_carry_program`) the kernel in
+    `carry.py` executes verbatim. `vmem_bytes` is the accounted
     per-instance footprint at the chosen `(tk, tb)` tiles.
     """
 
@@ -135,7 +119,7 @@ class CarryPlan:
     @property
     def carry_bytes(self) -> int:
         """Peak bytes of the carried bond state for the FULL problem —
-        b * k * R_op * R_in floats, the `(B, k, R_op·R_in)` carry that
+        b * k * R_op * R_in floats, the `(R_op, R_in, B, k)` carry that
         replaces the dense path's (B, k, d2..dN) sweep intermediates."""
         return 4 * self.b * self.k * self.r_op * self.r_in
 
@@ -156,55 +140,61 @@ def plan_carry_sweep(op_family: str, in_family: str, k: int, b: int,
                      pipeline: str = "serial") -> CarryPlan:
     """Plan a carry-sweep kernel launch for static order N = len(dims).
 
-    Accounts every per-instance VMEM buffer — the per-k-tile operator
-    cores, the per-batch-tile input cores, the carry + temp peak of the
-    sweep (both live simultaneously inside a step), and the `(TB, TK)`
-    output block — and shrinks tiles until the footprint fits `budget`,
-    batch tile first (TK=128 keeps k on the lane axis; the cores the k-tile
-    pins in VMEM are what the whole schedule exists to keep resident).
+    TK is the 128-lane width (or all of a smaller k); the batch tile starts
+    at 128 sublanes and halves until the footprint fits `budget`, down to
+    the eight-sublane floor (or all of a batch of at most eight). The
+    footprint counts the operator slabs `(op_src, d, op_dst*TK)` and the
+    input slabs `(in_src, in_dst*TB, d)` of every mode twice (Pallas
+    double-buffers streamed blocks), the old and new carry, the widest
+    matmul result, and the `(TB, TK)` output block.
 
-    `pipeline='double'` (the double-buffered kernel) accounts a SECOND
-    slot of the per-batch-tile input cores plus the full `(B, TK)` output
-    block the in-kernel batch sweep writes through.
+    `pipeline='double'` (the double-buffered kernel) accounts its explicit
+    second input slot and the full-batch `(B, TK)` output block the
+    in-kernel batch sweep writes through.
     """
     dims = tuple(int(d) for d in dims)
-    program = _carry_program(op_family, in_family, len(dims))  # validates
     validate_pipeline(pipeline)
     r_op, r_in = max(1, int(r_op)), max(1, int(r_in))
+    program = _carry_program(op_family, in_family, len(dims), r_op, r_in)
+    terms = _unrolled_terms(program)
+    if terms > MAX_UNROLLED_TERMS:
+        raise KernelPlanError(
+            f"plan_carry_sweep: ranks ({r_op}, {r_in}) unroll {terms} bond "
+            f"updates per kernel body (> {MAX_UNROLLED_TERMS})")
     tk = _lane_tile(k)
-    tb = _pow2_at_most(max(1, b), 8)
-    op_elems = _core_elems(op_family, dims, r_op)
-    in_elems = _core_elems(in_family, dims, r_in)
-    # largest per-mode temp: the mode axis d is live between the two steps
-    # of a mode update for every pairing EXCEPT cp x cp, whose temp is the
-    # modeless (b, k, r, p) Hadamard operand
-    temp_d = 1 if (op_family, in_family) == ("cp", "cp") else max(dims)
 
-    def footprint(tk: int, tb: int) -> int:
-        carry = tb * tk * r_op * r_in
-        temp = tb * tk * r_op * r_in * temp_d
+    def slab_elems(tb: int):
+        op = inp = widest = 0
+        for (_, oc, o_src, o_dst, ic, i_src, i_dst), d in zip(program, dims):
+            o_loops = o_src if oc == "full" else 1
+            i_loops = i_src if ic == "full" else 1
+            op += o_loops * d * o_dst * tk
+            inp += i_loops * i_dst * tb * d
+            widest = max(widest, i_dst * tb * o_dst * tk)
+        return op, inp, widest
+
+    def footprint(tb: int) -> int:
+        op, inp, widest = slab_elems(tb)
+        carry = 2 * r_op * r_in * tb * tk
         if pipeline == "double":
-            # second input-core slot + the full-batch output block the
-            # in-kernel sweep writes tile by tile
             out = -(-b // tb) * tb * tk
-            extra = tb * in_elems
+            extra = inp
         else:
             out = tb * tk
             extra = 0
-        return 4 * (tk * op_elems + tb * in_elems + carry + temp + out
-                    + extra)
+        return 4 * (2 * (op + inp + out) + extra + carry + widest)
 
-    for axis in ("tb", "tk"):
-        while footprint(tk, tb) > budget:
-            if axis == "tb" and tb > 1:
-                tb //= 2
-            elif axis == "tk" and tk > 8:
-                tk //= 2
-            else:
-                break
+    tb = _batch_tile(b, 128)
+    while footprint(tb) > budget and tb > SUBLANES:
+        tb //= 2
+    if footprint(tb) > budget:
+        raise KernelPlanError(
+            f"plan_carry_sweep: dims={dims}, ranks ({r_op}, {r_in}) need "
+            f"{footprint(tb)} bytes of VMEM at the aligned tile floor "
+            f"(tk={tk}, tb={tb}) > budget {budget}")
     return CarryPlan(op_family=op_family, in_family=in_family, k=k, b=b,
                      dims=dims, r_op=r_op, r_in=r_in, tk=tk, tb=tb,
-                     program=program, vmem_bytes=footprint(tk, tb),
+                     program=program, vmem_bytes=footprint(tb),
                      pipeline=pipeline)
 
 

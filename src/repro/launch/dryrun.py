@@ -24,6 +24,7 @@ import jax
 from repro.configs import ARCHS, get_config
 from repro.launch import roofline as rl
 from repro.launch import steps
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import build_model, settings
 
@@ -174,6 +175,7 @@ def main(argv=None) -> int:
     ap.add_argument("--tag", default="", help="suffix for output json")
     ap.add_argument("--list", action="store_true")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.list:
         for name, cfg in ARCHS.items():

@@ -13,6 +13,26 @@ Axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType, Mesh
+
+
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """`jax.make_mesh` with every axis Auto: the code places arrays with
+    `with_sharding_constraint` and partially-manual `shard_map`, which
+    refer to Auto axes only (`jax.make_mesh` defaults to Explicit)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
+def auto_axes(mesh):
+    """The same devices and axis names as `mesh`, every axis Auto — what
+    every sharding entry point normalizes a caller's mesh to (a mesh built
+    by `jax.make_mesh` has Explicit axes). None passes through."""
+    if mesh is None or all(t == AxisType.Auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,7 +47,7 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, found {len(devices)}; the "
             "dry-run must set XLA_FLAGS=--xla_force_host_platform_device_count"
             "=512 before any jax import")
-    return jax.make_mesh(shape, axes, devices=devices[:need])
+    return make_mesh(shape, axes, devices=devices[:need])
 
 
 def make_host_mesh(model: int = 1):
@@ -40,7 +60,7 @@ def make_host_mesh(model: int = 1):
             f"device(s); pick a model-parallel size that divides {n} (or "
             "force more host devices via "
             "XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

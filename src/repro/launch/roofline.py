@@ -7,8 +7,10 @@
 HLO_FLOPs / bytes come from compiled.cost_analysis(). Collective bytes are
 NOT in cost_analysis: we parse the post-partitioning HLO (compiled.as_text())
 and apply a ring-algorithm traffic model per op type using the replica-group
-size. Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s/link ICI (the `pod` axis crosses DCN; flagged separately).
+size. Hardware constants come from `chip_peaks(device_kind)`, one table
+keyed by the `device_kind` JAX reports; the dry-run targets a TPU v5e
+production mesh (the `pod` axis crosses DCN; flagged separately). A device
+kind missing from the table is an error, never a default.
 """
 from __future__ import annotations
 
@@ -17,10 +19,43 @@ import json
 import re
 from typing import Any
 
-# TPU v5e per-chip constants
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-ICI_BW = 50e9                # bytes/s per link
+
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator kind."""
+
+    flops: float                 # bf16 FLOP/s
+    int8_ops: float              # int8 OP/s
+    hbm_bw: float                # bytes/s
+    hbm_bytes: float
+    ici_link_bw: float           # bytes/s per inter-chip link
+    source: str
+
+
+# Keyed by `jax.devices()[0].device_kind`.
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, int8_ops=393e12, hbm_bw=819e9, hbm_bytes=16e9,
+        ici_link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of "
+               "inter-chip interconnect over four links"),
+}
+# The chip the dry-run's production mesh and the analytic kernel
+# rooflines are sized for.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The peak table entry for `device_kind`; unknown kinds raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak table entry for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)} — add the chip's published peaks with their "
+            "source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -101,6 +136,7 @@ class Roofline:
     collective: dict
     model_flops: float           # 6*N*D (active params) for the global step
     memory_per_device: dict
+    device_kind: str = TARGET_DEVICE_KIND
     compute_s: float = 0.0
     memory_s: float = 0.0
     collective_s: float = 0.0
@@ -108,9 +144,11 @@ class Roofline:
     useful_flops_frac: float = 0.0
 
     def finalize(self) -> "Roofline":
-        self.compute_s = self.hlo_flops_per_device / PEAK_FLOPS
-        self.memory_s = self.hlo_bytes_per_device / HBM_BW
-        self.collective_s = self.collective["link_bytes_per_device"] / ICI_BW
+        peaks = chip_peaks(self.device_kind)
+        self.compute_s = self.hlo_flops_per_device / peaks.flops
+        self.memory_s = self.hlo_bytes_per_device / peaks.hbm_bw
+        self.collective_s = (self.collective["link_bytes_per_device"]
+                             / peaks.ici_link_bw)
         terms = {"compute": self.compute_s, "memory": self.memory_s,
                  "collective": self.collective_s}
         self.bottleneck = max(terms, key=terms.get)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, list_archs, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
 
@@ -134,6 +135,7 @@ def main(argv=None) -> int:
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--max-seq", type=int, default=128)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -22,6 +22,7 @@ import argparse
 import contextlib
 
 from repro import obs, rp
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import (ServeConfig, SketchServer, SketchStore, replay,
                          synth_trace)
 
@@ -65,6 +66,7 @@ def main(argv=None) -> int:
                     help="stream dense-request distortion through a "
                          "DistortionMonitor at this (eps, delta) target")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     spec = rp.ProjectorSpec(family=args.family, k=args.k,
                             dims=tuple(args.dims), rank=args.rank)

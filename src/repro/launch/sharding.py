@@ -24,7 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models.config import ArchConfig
 
-from .mesh import data_axes, model_size
+from .mesh import auto_axes, data_axes, model_size
 
 
 def axis_rules(cfg: ArchConfig, mesh, *, fsdp_axes=None) -> dict[str, Any]:
@@ -193,4 +193,4 @@ def shard_batch_seq(x, mesh, *, seq_axis: int = 1, exclude: tuple = ()):
     if x.shape[seq_axis] % model_size(mesh) == 0:
         entries[seq_axis] = "model"
     return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, P(*entries)))
+        x, NamedSharding(auto_axes(mesh), P(*entries)))

@@ -20,7 +20,7 @@ from repro.models.config import ArchConfig, ShapeSpec
 from repro.optim import AdamWConfig, adamw, schedule
 
 from . import sharding as sh
-from .mesh import data_axes, model_size
+from .mesh import auto_axes, data_axes, model_size
 
 
 def _policy(cfg: ArchConfig):
@@ -74,6 +74,7 @@ def build_train_step(model: Model, mesh, shape: ShapeSpec, *,
     Requires a compressor, no pod axis (the collective branch syncs
     sketches across pods before the optimizer and keeps the unfused
     update), and `AdamWConfig(clip_norm=None)`."""
+    mesh = auto_axes(mesh)
     cfg = model.cfg
     pol = _policy(cfg)
     opt = opt or AdamWConfig(moment_dtype=pol["moment_dtype"])
@@ -169,8 +170,6 @@ def build_train_step(model: Model, mesh, shape: ShapeSpec, *,
                 manual_axes=(pod_axis,) if pod_axis else ()):
             return jax.value_and_grad(loss_f)(params)
 
-    interpret = jax.default_backend() != "tpu"
-
     def train_step(state, batch):
         params = state["params"]
         metrics = {}
@@ -183,7 +182,7 @@ def build_train_step(model: Model, mesh, shape: ShapeSpec, *,
             lr = lr_fn(state["opt"]["count"])
             new_p, new_opt, new_state["ef"], cmet = adamw.update_sketched(
                 params, grads, state["ef"], state["opt"], lr, opt,
-                compressor=compressor, interpret=interpret)
+                compressor=compressor)
             metrics.update(cmet)
             metrics["loss"] = loss
             metrics["lr"] = lr
@@ -273,6 +272,7 @@ def init_train_state(model: Model, key, *, opt: AdamWConfig | None = None,
 def build_prefill_step(model: Model, mesh, shape: ShapeSpec, *,
                        remat: str = "nothing",
                        seq_parallel: bool = True) -> StepBundle:
+    mesh = auto_axes(mesh)
     cfg = model.cfg
     pol = _policy(cfg)
     notes: list = []
@@ -325,6 +325,7 @@ def build_prefill_step(model: Model, mesh, shape: ShapeSpec, *,
 # ---------------------------------------------------------------------------
 
 def build_serve_step(model: Model, mesh, shape: ShapeSpec) -> StepBundle:
+    mesh = auto_axes(mesh)
     cfg = model.cfg
     pol = _policy(cfg)
     notes: list = []

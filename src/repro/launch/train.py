@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from repro.configs import get_config, list_archs, reduced
 from repro.data import DataConfig, SyntheticLM
 from repro.launch import steps as steps_lib
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh, make_mesh
 from repro.models import build_model
 from repro.models.config import ShapeSpec
 from repro.optim import schedule
@@ -38,7 +39,7 @@ def parse_mesh(spec: str | None):
         return make_host_mesh()
     dims = tuple(int(x) for x in spec.split("x"))
     names = {2: ("data", "model"), 3: ("pod", "data", "model")}[len(dims)]
-    return jax.make_mesh(dims, names, devices=jax.devices()[: _prod(dims)])
+    return make_mesh(dims, names, devices=jax.devices()[: _prod(dims)])
 
 
 def _prod(xs):
@@ -80,6 +81,7 @@ def main(argv=None) -> int:
     ap.add_argument("--monitor", action="store_true",
                     help="O(k) sketch telemetry: param norm/drift per log")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

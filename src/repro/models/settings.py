@@ -66,6 +66,10 @@ def scan_unroll():
 
 @contextlib.contextmanager
 def override(**kw):
+    if kw.get("mesh") is not None:
+        # runtime import: launch/mesh imports only jax
+        from repro.launch.mesh import auto_axes
+        kw["mesh"] = auto_axes(kw["mesh"])
     cur = _settings.get()
     token = _settings.set(dataclasses.replace(cur, **kw))
     try:

@@ -81,7 +81,7 @@ def update(params, grads, state, lr, cfg: AdamWConfig):
 
 
 def update_sketched(params, grads, ef_state, opt_state, lr,
-                    cfg: AdamWConfig, *, compressor, interpret: bool = True):
+                    cfg: AdamWConfig, *, compressor):
     """Fused sketch-compressed AdamW step: one kernel launch per leaf.
 
     Semantically equal (to fp32 kernel tolerance) to the unfused chain
@@ -143,16 +143,18 @@ def update_sketched(params, grads, ef_state, opt_state, lr,
     fused_hbm = 0
     for pe, w, m, v, nb, size, shape in zip(
             flat_pe, flat_w, flat_m, flat_v, sk._nb, sk._sizes, sk._shapes):
+        plan = rp.plan_update(op, nb, fused=True)
         rp.count_kernel_dispatch(family=compressor.cfg.family,
                                  structure="fused-update",
-                                 order=len(compressor.cfg.dims))
-        fused_hbm += rp.plan_update(op, nb, fused=True).cost.hbm_bytes
+                                 order=len(compressor.cfg.dims),
+                                 interpret=plan.interpret)
+        fused_hbm += plan.cost.hbm_bytes
         r_b, w_b, m_b, v_b = fused_update_buckets(
             op, y[off:off + nb],
             sk._leaf_to_buckets(pe, nb), sk._leaf_to_buckets(w, nb),
             sk._leaf_to_buckets(m, nb), sk._leaf_to_buckets(v, nb),
             lr, c1, c2, alpha=alpha, b1=cfg.b1, b2=cfg.b2, eps=cfg.eps,
-            weight_decay=cfg.weight_decay, interpret=interpret)
+            weight_decay=cfg.weight_decay, interpret=plan.interpret)
         off += nb
         new_r.append(sk._leaf_from_buckets(r_b, size, shape, jnp.float32))
         new_w.append(sk._leaf_from_buckets(w_b, size, shape, w.dtype))

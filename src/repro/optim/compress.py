@@ -45,7 +45,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.formats import BatchedCPTensor, BatchedTTTensor
@@ -381,10 +380,11 @@ class SketchCompressor:
         pod_specs = jax.tree.map(lambda _: P(axis), grads_pp)
         res_specs = jax.tree.map(lambda _: P(axis), state["residual"])
         out_specs = (jax.tree.map(lambda _: P(), example), res_specs)
-        f = shard_map(body, mesh=mesh,
-                      in_specs=(pod_specs, res_specs), out_specs=out_specs,
-                      check_rep=False,
-                      auto=frozenset(mesh.axis_names) - {axis})
+        from repro.launch.mesh import auto_axes
+        f = jax.shard_map(body, mesh=auto_axes(mesh),
+                          in_specs=(pod_specs, res_specs),
+                          out_specs=out_specs, axis_names=frozenset({axis}),
+                          check_vma=False)
         g_out, new_residual = f(grads_pp, state["residual"])
         return g_out, {"residual": new_residual}, self._pod_metrics(
             sk, new_residual)

@@ -51,6 +51,8 @@ class DispatchStats:
 
     kernel_calls : number of `project`/`reconstruct` dispatches that routed
                    to a Pallas kernel in this context.
+    interpret_calls : how many of those ran the kernel in interpret mode
+                   (the plan's `interpret`; 0 on a TPU backend).
     force_depth  : nesting depth of active `force_pallas()` scopes; > 0
                    lets 'auto' pick the interpret-mode kernel off-TPU.
     breakdown    : per-(family, structure, route, order) dispatch counts,
@@ -63,6 +65,7 @@ class DispatchStats:
     """
 
     kernel_calls: int = 0
+    interpret_calls: int = 0
     force_depth: int = 0
     breakdown: dict = dataclasses.field(default_factory=dict)
 
@@ -71,12 +74,14 @@ class DispatchStats:
         return self.force_depth > 0
 
     def record(self, family: str, structure: str, route: str,
-               order: int) -> None:
-        """Count one dispatch; pallas routes also bump `kernel_calls`."""
+               order: int, *, interpret: bool) -> None:
+        """Count one dispatch; pallas routes also bump `kernel_calls` (and
+        `interpret_calls` when the kernel runs in interpret mode)."""
         key = (family, structure, route, order)
         self.breakdown[key] = self.breakdown.get(key, 0) + 1
         if route == "pallas":
             self.kernel_calls += 1
+            self.interpret_calls += int(interpret)
 
     def breakdown_table(self) -> list[dict]:
         """The breakdown as sorted JSON-able rows (telemetry sinks)."""
@@ -148,7 +153,7 @@ def dispatch_breakdown() -> dict:
 
 
 def count_kernel_dispatch(family: str = "extern", structure: str = "extern",
-                          order: int = 0) -> None:
+                          order: int = 0, *, interpret: bool) -> None:
     """Record one Pallas kernel dispatch on the context-local stats.
 
     The public hook for kernel wrappers that live OUTSIDE the
@@ -159,8 +164,10 @@ def count_kernel_dispatch(family: str = "extern", structure: str = "extern",
     `breakdown` (route is 'pallas' by definition here — this hook exists
     for kernel launches); untagged calls land under ('extern', 'extern',
     'pallas', 0), keeping the kernel_calls == sum-of-pallas-rows invariant.
+    `interpret` is the launching plan's (`ExecutionPlan.interpret`).
     """
-    _STATS.get().record(family, structure, "pallas", int(order))
+    _STATS.get().record(family, structure, "pallas", int(order),
+                        interpret=interpret)
 
 
 def _coerce_dense(op: RPOperator, x: jnp.ndarray) -> jnp.ndarray:
@@ -222,7 +229,7 @@ def _check_struct_dims(op: RPOperator, x) -> None:
 def _run_planned(span_name: str, eplan, op, x) -> jnp.ndarray:
     """Record one dispatch on the context stats and execute the plan."""
     _STATS.get().record(eplan.family, eplan.structure, eplan.route,
-                        eplan.order)
+                        eplan.order, interpret=eplan.interpret)
     with obs.span(span_name, family=eplan.family, structure=eplan.structure,
                   order=eplan.order, backend=eplan.route,
                   pipeline=eplan.pipeline, plan=eplan.plan_id):
@@ -296,7 +303,7 @@ def reconstruct(op: RPOperator, y: jnp.ndarray, *, chunk: int | None = None,
 
     A `(k,)` sketch returns an `in_dims`-shaped estimate (the original
     contract); batched sketches route to the batched mode-sweep adjoint
-    kernels (`tt_sweep_reconstruct` / `cp_sweep_reconstruct`, any order
+    kernels (`tt_reconstruct` / `cp_reconstruct`, any order
     N >= 2) under the same backend policy as `project` — ONE launch for the
     whole batch, no vmap — and otherwise fall back to a vmap of the
     operator's einsum adjoint.

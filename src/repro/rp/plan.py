@@ -36,13 +36,22 @@ O(k N d R R~ (R + R~)), never densifying). Routing:
 * 'xla'    — always the einsum path.
 * 'pallas' — always the kernel (operators outside the supported order
              range — order-1 classical Gaussian, order > MAX_ORDER — take
-             the einsum path); interpret mode off-TPU.
+             the einsum path); interpret mode off-TPU. A kernel the planner
+             cannot fit (`KernelPlanError`) raises.
 * 'auto'   — the kernel iff the shapes are MXU-aligned (k a multiple of the
              128 lane width, every mode a multiple of the 8 sublanes, order
              >= 2) AND we are on real TPU hardware. Off-TPU the kernels
              only run in interpret mode — a validation device, not a fast
              path — so 'auto' stays on XLA there unless `force_pallas()` is
-             active (which tests use to prove the routing).
+             active (which tests use to prove the routing). A kernel the
+             planner cannot fit within the TPU's VMEM at the aligned tile
+             floor takes the einsum route, with the planner's reason
+             recorded in `rejected`.
+
+`interpret` is decided here and nowhere else (`kernel_interpret`): the
+kernels run compiled by Mosaic on a TPU backend and in interpret mode on
+any other. Every kernel call — dispatch, the fused optimizer update, the
+benchmarks — reads it from its plan.
 
 `chunk` on reconstruct is part of the plan, not a warning: the kernel
 route records `chunk_policy='folded'` (the planner's VMEM budget already
@@ -217,6 +226,7 @@ class ExecutionPlan:
     grid: tuple | None
     rejected: tuple                # ((route, reason), ...)
     cost: CostLedger
+    interpret: bool                # kernel route: interpret mode (off-TPU)
     carry_bytes: int = 0           # structured routes: the (B, k, R·R~)
                                    # bond state replacing dense sweep temps
 
@@ -233,7 +243,9 @@ class ExecutionPlan:
             f"{self.family}/{self.structure} N={self.order}",
             "",
             f"* route: **{self.route}** (requested backend="
-            f"'{self.backend}', pipeline='{self.pipeline}')",
+            f"'{self.backend}', pipeline='{self.pipeline}'"
+            + (", interpret mode" if self.interpret and self.route == "pallas"
+               else "") + ")",
             f"* kernel: {self.kernel}",
             f"* shape: k={self.k} dims={'x'.join(map(str, self.dims))} "
             f"rank={self.rank} batch={self.batch}"
@@ -327,6 +339,13 @@ def _order_tag(op) -> int:
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def kernel_interpret() -> bool:
+    """THE interpret decision: Pallas kernels compile for the chip on a TPU
+    backend and run in interpret mode on any other. Plans carry it
+    (`ExecutionPlan.interpret`); nothing else decides it."""
+    return not _on_tpu()
 
 
 def _aligned(k: int, dims: tuple) -> bool:
@@ -505,6 +524,21 @@ def _build_plan(op_sig: _OpSig, sig: StructureSig, kind: str, backend: str,
     tiles = grid = None
     vmem = 0
     carry = 0
+    kplan = None
+    if route == "pallas":
+        try:
+            if sig.structure in ("tt", "cp"):
+                kplan = ksplan.plan_carry_sweep(f, sig.structure, k, b, dims,
+                                                rank, sig.in_rank,
+                                                pipeline=pipeline)
+            else:
+                kplan = kops.plan_contraction(f, kind, k, b, dims, rank,
+                                              pipeline=pipeline)
+        except kops.KernelPlanError as e:
+            if backend == "pallas":
+                raise
+            route, rejected = "xla", (("pallas", f"kernel does not fit: "
+                                       f"{e}"),)
     if sig.structure in ("tt", "cp"):
         # structured input x TT/CP operator: the carry sweep
         per_item = theory.flops_project_struct(f, sig.structure, k, dims,
@@ -514,12 +548,9 @@ def _build_plan(op_sig: _OpSig, sig: StructureSig, kind: str, backend: str,
         carry = theory.mem_carry_struct(k, max(1, rank),
                                         max(1, sig.in_rank), batch=b)
         if route == "pallas":
-            cplan = ksplan.plan_carry_sweep(f, sig.structure, k, b, dims,
-                                            rank, sig.in_rank,
-                                            pipeline=pipeline)
-            tiles, grid = (cplan.tk, cplan.tb), cplan.grid
-            vmem = cplan.vmem_bytes
-            hbm = ksplan.struct_hbm_bytes(cplan)
+            tiles, grid = (kplan.tk, kplan.tb), kplan.grid
+            vmem = kplan.vmem_bytes
+            hbm = ksplan.struct_hbm_bytes(kplan)
         else:
             in_elems = ksplan._core_elems(sig.structure, dims,
                                           max(1, sig.in_rank))
@@ -536,8 +567,6 @@ def _build_plan(op_sig: _OpSig, sig: StructureSig, kind: str, backend: str,
             per_item = 2 * params
         flops = b * per_item
         if route == "pallas":
-            kplan = kops.plan_contraction(f, kind, k, b, dims, rank,
-                                          pipeline=pipeline)
             tiles, grid = (kplan.tk, kplan.tb, kplan.ba), kplan.grid
             vmem = kplan.vmem_bytes
             hbm = kops.sweep_hbm_bytes(kplan)
@@ -559,7 +588,7 @@ def _build_plan(op_sig: _OpSig, sig: StructureSig, kind: str, backend: str,
         cost=CostLedger(flops=int(flops), hbm_bytes=int(hbm),
                         vmem_bytes=int(vmem), wire_bytes=0, params=params,
                         var_factor=var),
-        carry_bytes=int(carry))
+        carry_bytes=int(carry), interpret=kernel_interpret())
 
 
 def plan_execution(op_spec, structure_sig: StructureSig | None = None, *,
@@ -658,23 +687,23 @@ def _exec_dense_project(plan: ExecutionPlan, op, xt):
     if plan.route == "xla":
         return op.project(xt)
     from repro.kernels import ops as kops
-    interpret = not _on_tpu()
     kern = kops.tt_project if plan.family == "tt" else kops.cp_project
     n = plan.order
     if xt.ndim <= n + 1:  # single input/1-D batch: native batch axis
-        return kern(op, xt, interpret=interpret, pipeline=plan.pipeline)
+        return kern(op, xt, interpret=plan.interpret, pipeline=plan.pipeline)
     batch = xt.shape[:-n]
     flat = xt.reshape((-1,) + xt.shape[-n:])
-    return kern(op, flat, interpret=interpret,
+    return kern(op, flat, interpret=plan.interpret,
                 pipeline=plan.pipeline).reshape(batch + (op.k,))
 
 
 def _exec_struct_project(plan: ExecutionPlan, op, x):
     from repro.kernels import struct as kstruct
     if plan.route == "pallas":
-        return kstruct.struct_project(op, x, interpret=not _on_tpu(),
+        return kstruct.struct_project(op, x, interpret=plan.interpret,
                                       pipeline=plan.pipeline)
-    return kstruct.struct_project(op, x, use_kernel=False)
+    return kstruct.struct_project(op, x, interpret=plan.interpret,
+                                  use_kernel=False)
 
 
 def _exec_reconstruct(plan: ExecutionPlan, op, y):
@@ -684,13 +713,12 @@ def _exec_reconstruct(plan: ExecutionPlan, op, y):
         # (plan.tiles[0]), so the requested bound is honored by the
         # kernel's own k-tiling — no dense (D, k) intermediate exists
         from repro.kernels import ops as kops
-        interpret = not _on_tpu()
         kern = (kops.tt_reconstruct if plan.family == "tt"
                 else kops.cp_reconstruct)
         if y.ndim <= 2:
-            return kern(op, y, interpret=interpret)
+            return kern(op, y, interpret=plan.interpret)
         batch = y.shape[:-1]
-        out = kern(op, y.reshape(-1, op.k), interpret=interpret)
+        out = kern(op, y.reshape(-1, op.k), interpret=plan.interpret)
         return out.reshape(batch + tuple(op.in_dims))
     if y.ndim == 1:
         return op.reconstruct(y, chunk=chunk)
@@ -793,7 +821,8 @@ def plan_update(op_spec, batch: int, *, fused: bool = True) -> ExecutionPlan:
             params=_safe_params(op_sig.family, op_sig.k, op_sig.dims,
                                 op_sig.rank),
             var_factor=_safe_var_factor(op_sig.family, op_sig.order,
-                                        op_sig.rank, op_sig.dims)))
+                                        op_sig.rank, op_sig.dims)),
+        interpret=kernel_interpret())
     _CACHE_STATS.builds += 1
     _PLAN_CACHE[key] = plan
     while len(_PLAN_CACHE) > _CACHE_CAP:
@@ -821,6 +850,7 @@ __all__ = [
     "BACKENDS", "CostLedger", "ExecutionPlan", "PlanCacheStats",
     "StructureSig", "clear_plan_cache", "collective_wire_bytes",
     "dense_signature", "execute_plan", "explain", "group_signature",
+    "kernel_interpret",
     "plan_cache_stats", "plan_execution", "plan_update", "pow2ceil",
     "sketch_signature", "struct_in_rank", "struct_signature",
     "structure_tag", "validate_backend", "validate_pipeline",

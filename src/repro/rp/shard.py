@@ -26,8 +26,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
+
+from repro.launch.mesh import auto_axes
 
 from .dispatch import project, reconstruct
 
@@ -78,10 +79,10 @@ def bucket_pspec(mesh, n_buckets: int, *, axes=None, exclude=()) -> P:
 
 def _sharded_apply(fn, op, x, *, mesh, spec, axes):
     """shard_map `fn(op, x_local)` with dim 0 of `x` laid out per `spec`."""
-    auto = frozenset(mesh.axis_names) - set(axes)
     op_specs = jax.tree.map(lambda _: P(), op)
-    f = shard_map(fn, mesh=mesh, in_specs=(op_specs, P(spec[0])),
-                  out_specs=P(spec[0]), check_rep=False, auto=auto)
+    f = jax.shard_map(fn, mesh=auto_axes(mesh), in_specs=(op_specs, P(spec[0])),
+                      out_specs=P(spec[0]), axis_names=frozenset(axes),
+                      check_vma=False)
     return f(op, x)
 
 

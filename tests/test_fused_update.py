@@ -45,7 +45,8 @@ def test_fused_matches_reference(dims, family):
                                     (nb,) + dims) for i in range(4))
     v = jnp.abs(v)  # second moment is nonnegative in real trajectories
     lr, c1, c2 = jnp.float32(1e-3), jnp.float32(0.1), jnp.float32(0.05)
-    got = fused_update_buckets(op, y, p, w, m, v, lr, c1, c2, **HP)
+    got = fused_update_buckets(op, y, p, w, m, v, lr, c1, c2, interpret=True,
+                               **HP)
     want = _reference(op, y, p, w, m, v, lr, c1, c2)
     for g, r in zip(got, want):
         assert g.shape == (nb,) + dims and g.dtype == jnp.float32
@@ -86,7 +87,7 @@ def test_fused_typed_errors():
     args = [jnp.zeros((2, k))] + [jnp.zeros((2,) + dims)] * 4
     scal = [jnp.float32(1e-3), jnp.float32(0.1), jnp.float32(0.05)]
     with pytest.raises(TypeError, match="TT/CP operator"):
-        fused_update_buckets(gop, *args, *scal, **HP)
+        fused_update_buckets(gop, *args, *scal, interpret=True, **HP)
     from repro.kernels import MAX_ORDER
     big = (2,) * (MAX_ORDER + 1)
     top = rp.make_projector(
@@ -94,7 +95,7 @@ def test_fused_typed_errors():
         jax.random.PRNGKey(3))
     args7 = [jnp.zeros((2, k))] + [jnp.zeros((2,) + big)] * 4
     with pytest.raises(ValueError, match="order"):
-        fused_update_buckets(top, *args7, *scal, **HP)
+        fused_update_buckets(top, *args7, *scal, interpret=True, **HP)
 
 
 # ---------------------------------------------------------------------------

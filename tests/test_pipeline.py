@@ -38,8 +38,8 @@ def test_sweep_pipelined_matches_serial(dims, family):
         jax.random.PRNGKey(0))
     xb = jax.random.normal(jax.random.PRNGKey(1), (b,) + dims)
     kern = tt_project if family == "tt" else cp_project
-    got = kern(op, xb, pipeline="double")
-    want = kern(op, xb, pipeline="serial")
+    got = kern(op, xb, interpret=True, pipeline="double")
+    want = kern(op, xb, interpret=True, pipeline="serial")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-5, atol=3e-5)
 
@@ -58,8 +58,8 @@ def test_sweep_pipelined_na1_edge(family):
     xb = jax.random.normal(jax.random.PRNGKey(3), (2,) + dims)
     kern = tt_project if family == "tt" else cp_project
     np.testing.assert_allclose(
-        np.asarray(kern(op, xb, pipeline="double")),
-        np.asarray(kern(op, xb, pipeline="serial")), rtol=3e-5, atol=3e-5)
+        np.asarray(kern(op, xb, interpret=True, pipeline="double")),
+        np.asarray(kern(op, xb, interpret=True, pipeline="serial")), rtol=3e-5, atol=3e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -12,8 +12,9 @@ from repro import rp
 
 
 def test_bucket_pspec_single_device():
+    from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("data",))
-    assert rp.bucket_pspec(mesh, 16)[0] == ("data",)
+    assert rp.bucket_pspec(mesh, 16) == P(("data",))
     assert rp.bucket_pspec(mesh, 16, exclude=("data",))[0] is None
 
 
@@ -35,12 +36,13 @@ def test_bucket_pspec_divisibility(subproc):
     out = subproc("""
 import jax
 from repro import rp
+from jax.sharding import PartitionSpec as P
 mesh = jax.make_mesh((2, 4), ("pod", "data"))
 assert rp.bucket_pspec(mesh, 8)[0] == ("pod", "data")
-assert rp.bucket_pspec(mesh, 2)[0] == ("pod",)          # largest valid prefix
+assert rp.bucket_pspec(mesh, 2) == P(("pod",))          # largest valid prefix
 assert rp.bucket_pspec(mesh, 3)[0] is None              # nothing divides
-assert rp.bucket_pspec(mesh, 8, exclude=("pod",))[0] == ("data",)
-assert rp.bucket_pspec(mesh, 8, axes=("data",))[0] == ("data",)
+assert rp.bucket_pspec(mesh, 8, exclude=("pod",)) == P(("data",))
+assert rp.bucket_pspec(mesh, 8, axes=("data",)) == P(("data",))
 print("PSPEC_OK")
 """, devices=8)
     assert "PSPEC_OK" in out

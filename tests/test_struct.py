@@ -101,30 +101,30 @@ def test_batched_containers_are_pytrees():
 
 def test_carry_program_order3_ttxtt():
     """The emitted program at order 3 is exactly the documented carry
-    schedule: create the (R, R~) carry at mode 1, one (op, input) update
-    pair per interior mode, collapse both bonds at mode N."""
-    prog = _carry_program("tt", "tt", 3)
-    assert prog == (("c", "kdu,bde->bkue", "g0", "x0"),
-                    ("t", "bkue,kudv->bkedv", "c", "g1"),
-                    ("c", "bkedv,bedf->bkvf", "t", "x1"),
-                    ("t", "bkue,kud->bked", "c", "g2"),
-                    ("c", "bked,bed->bk", "t", "x2"))
-    # cp x cp is the Hadamard form
-    prog_cc = _carry_program("cp", "cp", 3)
-    assert prog_cc[1] == ("t", "kdr,bdp->bkrp", "g1", "x1")
-    assert prog_cc[-1] == ("c", "bkrp,bkrp->bk", "c", "t")
+    schedule: create the (R, R~) carry at mode 1, one full (op, input)
+    bond update per interior mode, collapse both bonds at mode N."""
+    prog = _carry_program("tt", "tt", 3, 2, 4)
+    assert prog == (("mode", "full", 1, 2, "full", 1, 4),
+                    ("mode", "full", 2, 2, "full", 4, 4),
+                    ("mode", "full", 2, 1, "full", 4, 1))
+    # cp x cp is the Hadamard form: both interior couplings diagonal
+    prog_cc = _carry_program("cp", "cp", 3, 2, 4)
+    assert prog_cc[1] == ("mode", "diag", 2, 2, "diag", 4, 4)
+    assert prog_cc[-1] == ("mode", "full", 2, 1, "full", 4, 1)
 
 
 @pytest.mark.parametrize("op_family,in_family", PAIRINGS)
 @pytest.mark.parametrize("order", [2, 5, MAX_ORDER])
 def test_carry_program_every_step_is_two_operand(op_family, in_family, order):
-    prog = _carry_program(op_family, in_family, order)
-    assert prog[-1][0] == "c" and prog[-1][1].endswith("->bk")
-    for dst, spec, a, b in prog:
-        assert dst in ("c", "t")
-        assert spec.count(",") == 1
-        for src in (a, b):
-            assert src in ("c", "t") or src[0] in "gx"
+    """One step per mode, each pairing one operator slab with one input
+    slab; the unit bond fans out at mode 1 and back in at mode N."""
+    prog = _carry_program(op_family, in_family, order, 2, 3)
+    assert len(prog) == order
+    assert prog[0][1:4] == ("full", 1, 2) and prog[0][4:] == ("full", 1, 3)
+    assert prog[-1][1:4] == ("full", 2, 1) and prog[-1][4:] == ("full", 3, 1)
+    for step in prog[1:-1]:
+        assert step[1] == ("full" if op_family == "tt" else "diag")
+        assert step[4] == ("full" if in_family == "tt" else "diag")
 
 
 def test_plan_carry_sweep_tiles_and_grid():
@@ -132,9 +132,11 @@ def test_plan_carry_sweep_tiles_and_grid():
     assert plan.tk == 128 and plan.grid == (2, 1)
     assert plan.carry_bytes == 4 * 4 * 256 * 2 * 10
     assert plan.vmem_bytes <= 8 * 1024 * 1024
-    # huge ranks force the batch tile down before the k tile
-    fat = plan_carry_sweep("tt", "tt", 1024, 16, (128, 128, 128), 64, 64)
-    assert fat.tb < 8
+    # huge ranks would unroll too many bond updates: a typed error the
+    # execution plan records as the kernel route's rejection
+    from repro.kernels import KernelPlanError
+    with pytest.raises(KernelPlanError, match="unroll"):
+        plan_carry_sweep("tt", "tt", 1024, 16, (128, 128, 128), 64, 64)
     assert struct_hbm_bytes(plan) > 0
 
 
@@ -166,7 +168,7 @@ def test_carry_sweep_all_orders_vs_ref_and_dense(op_family, in_family,
     xb = _make_batch(in_family, dims, 3, b)
     got = struct_project(op, xb, interpret=True)
     assert got.shape == (b, k)
-    want_ref = struct_project(op, xb, use_kernel=False)
+    want_ref = struct_project(op, xb, use_kernel=False, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want_ref),
                                rtol=2e-4, atol=2e-4)
     want_dense = op.project(xb.full())
@@ -179,8 +181,8 @@ def test_carry_sweep_unbatched_matches_batch_row(op_family, in_family):
     dims, k = (16, 32, 24), 128
     op = _make_op(op_family, dims, k, 3)
     xb = _make_batch(in_family, dims, 2, 4)
-    yb = struct_project(op, xb)
-    y1 = struct_project(op, xb[1])
+    yb = struct_project(op, xb, interpret=True)
+    y1 = struct_project(op, xb[1], interpret=True)
     assert y1.shape == (k,)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(yb[1]),
                                rtol=1e-5, atol=1e-5)
@@ -193,9 +195,9 @@ def test_carry_sweep_ragged_batches(b):
     dims, k = (8, 16, 16), 128
     op = _make_op("tt", dims, k, 2)
     xb = _make_batch("tt", dims, 2, b)
-    got = struct_project(op, xb)
+    got = struct_project(op, xb, interpret=True)
     assert got.shape == (b, k)
-    want = struct_project(op, xb, use_kernel=False)
+    want = struct_project(op, xb, use_kernel=False, interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 
@@ -209,7 +211,7 @@ def test_carry_sweep_cp_weights_fold():
     w = jnp.arange(1.0, 4.0)
     xw = CPTensor(base.factors, w)
     for use_kernel in (True, False):
-        got = struct_project(op, xw, use_kernel=use_kernel)
+        got = struct_project(op, xw, use_kernel=use_kernel, interpret=True)
         want = op.project(xw.full())
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
@@ -245,7 +247,7 @@ def test_struct_refs_match_operator_methods():
 def test_struct_project_order1_falls_back_dense():
     op = _make_op("tt", (64,), 32, 1)
     x = TTTensor((jax.random.normal(KEY, (1, 64, 1)),))
-    got = struct_project(op, x)
+    got = struct_project(op, x, interpret=True)
     np.testing.assert_allclose(np.asarray(got),
                                np.asarray(op.project(x.full())),
                                rtol=1e-5, atol=1e-5)
@@ -254,13 +256,13 @@ def test_struct_project_order1_falls_back_dense():
 def test_struct_project_typed_errors():
     op = _make_op("tt", (4, 6, 5), 64, 2)
     with pytest.raises(ValueError, match="input dims"):
-        struct_project(op, _make_input("tt", (5, 6, 4), 2))
+        struct_project(op, _make_input("tt", (5, 6, 4), 2), interpret=True)
     with pytest.raises(TypeError, match="structured input"):
-        struct_project(op, jnp.zeros((4, 6, 5)))
+        struct_project(op, jnp.zeros((4, 6, 5)), interpret=True)
     from repro.core import GaussianRP
     g = GaussianRP(key=KEY, k=8, dim=120)
     with pytest.raises(TypeError, match="TT/CP operator"):
-        struct_project(g, _make_input("tt", (4, 6, 5), 2))
+        struct_project(g, _make_input("tt", (4, 6, 5), 2), interpret=True)
 
 
 # ---------------------------------------------------------------------------
