@@ -222,6 +222,7 @@ def sweep_project(x: jnp.ndarray, *weights: jnp.ndarray, steps, tk: int,
                            lead_pos=2)
     return pl.pallas_call(
         functools.partial(_project_kernel, steps=steps, scale=scale),
+        name="sweep_project",
         grid=(k // tk, b // tb, d1 // ba),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tb, tk), _imap(1, 0)),
@@ -305,6 +306,7 @@ def sweep_project_pipelined(x: jnp.ndarray, *weights: jnp.ndarray, steps,
         functools.partial(_project_pipelined_kernel, steps=steps,
                           scale=scale, na=d1 // ba, tk=tk, tb=tb, ba=ba,
                           q=q),
+        name="sweep_project_pipelined",
         grid=(k // tk, b // tb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tb, tk), _imap(1, 0)),
@@ -333,6 +335,7 @@ def sweep_reconstruct(y: jnp.ndarray, *weights: jnp.ndarray, steps,
                            lead_pos=1)
     return pl.pallas_call(
         functools.partial(_reconstruct_kernel, steps=steps, scale=scale),
+        name="sweep_reconstruct",
         grid=(b // tb, d1 // ba, k // tk),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tb, ba * q, ell), _imap(0, 1, None)),

@@ -148,6 +148,7 @@ def _fused_launch(y, s, *arrs, steps, tk, tb, ba, scale, b1, b2, eps, wd,
         functools.partial(_fused_kernel, steps=steps, n_core=len(cores),
                           scale=scale, b1=b1, b2=b2, eps=eps, wd=wd,
                           nk=k // tk),
+        name="fused_update",
         grid=(b // tb, d1 // ba, k // tk),
         in_specs=in_specs,
         out_specs=(dense_spec,) * 4,

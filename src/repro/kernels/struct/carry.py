@@ -93,6 +93,7 @@ def carry_sweep_project(*operands: jnp.ndarray, program, tk: int, tb: int,
     return pl.pallas_call(
         functools.partial(_carry_kernel, program=program, scale=scale,
                           tb=tb, tk=tk),
+        name="carry_sweep_project",
         grid=(nk, nb),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((tb, tk), lambda ik, ib: (ib, ik)),
@@ -157,6 +158,7 @@ def carry_sweep_project_pipelined(*operands: jnp.ndarray, program, tk: int,
     return pl.pallas_call(
         functools.partial(_carry_pipelined_kernel, program=program,
                           scale=scale, nb=nb, tb=tb, tk=tk),
+        name="carry_sweep_project_pipelined",
         grid=(nk,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((nb * tb, tk), lambda ik: (0, ik)),

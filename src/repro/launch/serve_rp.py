@@ -12,8 +12,9 @@ PYTHONPATH=src python -m repro.launch.serve_rp --family tt --k 128 \
 
 With `--trace-out trace.json --metrics-out metrics.jsonl` the replay runs
 under an enabled `repro.obs` session: the trace opens in ui.perfetto.dev
-(per-tick serve spans over the rp dispatch spans they contain), the JSONL
-carries the queue-delay histogram and request counters, and
+(per-tick serve spans over the rp dispatch spans they contain, and the
+demo query's store spans), the JSONL carries the queue-delay histogram
+and the store's host-to-device byte counter, and
 `python -m repro.launch.obs_report` renders both as markdown.
 """
 from __future__ import annotations
@@ -91,6 +92,11 @@ def main(argv=None) -> int:
            else contextlib.nullcontext())
     with cap, rp.dispatch_stats() as st:
         report = replay(server, trace)
+        # Similarity demo: nearest stored neighbours of the first sketch
+        # (its own id comes back first, distance ~0 — a useful sanity
+        # check), recorded with the replay.
+        top_m = min(args.top_m, len(store))
+        res = server.query(store.get(0), top_m) if len(store) > 1 else None
     # kernel_calls counts PALLAS-routed dispatches; on the XLA route (the
     # CPU default under backend=auto) it stays 0 — don't claim otherwise.
     disp = (f"{st.kernel_calls} pallas dispatches — one per tick"
@@ -123,11 +129,7 @@ def main(argv=None) -> int:
                   f"out-rate {row['out_rate']:.3f} @ eps={row['eps']} "
                   f"(alerted={row['alerted']})")
 
-    # Similarity demo: nearest stored neighbours of the first sketch (its
-    # own id comes back first, distance ~0 — a useful sanity check).
-    if len(store) > 1:
-        top_m = min(args.top_m, len(store))
-        res = server.query(store.get(0), top_m)
+    if res is not None:
         ids = ", ".join(str(int(i)) for i in res.ids)
         print(f"[serve_rp] top-{top_m} of sketch 0: ids [{ids}]  "
               f"d2 {res.dist2.round(2).tolist()}")

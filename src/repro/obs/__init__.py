@@ -34,10 +34,12 @@ Wired call sites (all behind the disabled fast path):
                        order, backend, pipeline) + the launch breakdown
   serve.engine       — per-tick spans, queue-delay histograms, distortion
                        feed for dense payloads
+  serve.store        — per-query `store.query` span with per-tile
+                       `store.upload`/`store.fetch`/`store.merge` spans
+                       nested in it; the `store/h2d_bytes` counter
   runtime.train_loop — per-step spans; straggler/resume/fallback events
   ckpt.checkpointer  — save/verify/restore spans (async saves on their
                        own thread track) + fallback events
-  optim.compress     — collective wire-byte gauges/counters (trace-time)
 """
 from __future__ import annotations
 
@@ -117,8 +119,9 @@ def enable(*, tracer: Tracer | None = None,
     object stays valid for export.
     """
     global _STATE
-    ctx = ObsContext(tracer=tracer or Tracer(),
-                     metrics=metrics or MetricsRegistry(),
+    # `is None`, not truthiness: a Tracer with no events yet has len 0
+    ctx = ObsContext(tracer=Tracer() if tracer is None else tracer,
+                     metrics=MetricsRegistry() if metrics is None else metrics,
                      distortion=distortion)
     if distortion is not None and distortion.on_alert is None:
         def _on_alert(alert, ctx=ctx):

@@ -10,9 +10,10 @@ in the SAME trace on their own `tid` lane, sharing one timeline with the
 caller's spans. That is exactly what the Perfetto UI renders: one process
 row, one track per thread.
 
-Every span also enters `jax.named_scope` and (on non-trivial backends)
-`jax.profiler.TraceAnnotation`, so a device profile captured around the
-same region lines up name-for-name with the host spans exported here.
+Every span also enters `jax.named_scope` and `jax.profiler.TraceAnnotation`
+(a no-op unless the jax profiler is capturing), so a device profile
+captured around the same region lines up name-for-name with the host spans
+exported here.
 
 Export misuse is a typed `ValueError` that survives ``python -O``:
 exporting while spans are still open would emit a trace whose durations
@@ -72,7 +73,11 @@ class Tracer:
     """Thread-safe span/instant recorder with Chrome trace-event export."""
 
     def __init__(self, *, pid: int | None = None):
+        import jax      # here, not per span: an enabled span's hot path
+
         self.pid = os.getpid() if pid is None else pid
+        self._named_scope = jax.named_scope
+        self._annotation = jax.profiler.TraceAnnotation
         self._lock = threading.Lock()
         self._events: list[dict] = []
         self._open = 0          # spans entered but not yet exited (global)
@@ -83,8 +88,9 @@ class Tracer:
         """Record one complete span around the with-body.
 
         Nesting depth comes from the context-local stack; the body also
-        runs under `jax.named_scope(name)` (and `TraceAnnotation` when the
-        profiler supports it) so device-side profiles align with this span.
+        runs under `jax.named_scope(name)` and
+        `jax.profiler.TraceAnnotation(name)`, so device-side profiles align
+        with this span.
         """
         stack = _SPAN_STACK.get()
         handle = SpanHandle(name, dict(attrs), _now_us(), len(stack))
@@ -93,7 +99,7 @@ class Tracer:
             self._open += 1
         tid = threading.get_ident()
         try:
-            with _device_scope(name):
+            with self._named_scope(name), self._annotation(name):
                 yield handle
         finally:
             t1 = _now_us()
@@ -162,25 +168,3 @@ class Tracer:
                     f"cannot clear a trace with {self._open} unclosed "
                     "span(s)")
             self._events.clear()
-
-
-@contextlib.contextmanager
-def _device_scope(name: str):
-    """jax.named_scope + TraceAnnotation around a span body.
-
-    Both are best-effort: named_scope only affects code that is tracing
-    jaxprs, TraceAnnotation only shows up when the jax profiler is
-    capturing. Neither may break the span on an exotic backend.
-    """
-    import jax
-
-    with contextlib.ExitStack() as es:
-        try:
-            es.enter_context(jax.named_scope(name))
-        except Exception:       # pragma: no cover - defensive
-            pass
-        try:
-            es.enter_context(jax.profiler.TraceAnnotation(name))
-        except Exception:       # pragma: no cover - defensive
-            pass
-        yield

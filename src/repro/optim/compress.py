@@ -395,14 +395,7 @@ class SketchCompressor:
         describe the fp32 sketch-mean formulation and would misreport
         'local-mean' or int8 comm on dashboards."""
         metrics = self._metrics(sk, residual)
-        wire = self.wire_bytes(sk)
-        metrics["wire_bytes"] = jnp.asarray(wire, jnp.float32)
-        # TRACE-TIME telemetry: under jit this runs once per compiled
-        # variant, not once per step — the gauge is the analytic per-step
-        # payload (a constant of the config), the counter tallies traces
-        from repro import obs
-        obs.gauge("rp/wire_bytes_per_step").set(float(wire))
-        obs.counter("rp/collective_traces").inc()
+        metrics["wire_bytes"] = jnp.asarray(self.wire_bytes(sk), jnp.float32)
         return metrics
 
     def wire_bytes(self, sk: PytreeSketcher) -> int:

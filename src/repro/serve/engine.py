@@ -119,7 +119,6 @@ class SketchServer:
                             np.asarray(ys[i], np.float64)))),
                         rank=key.spec.rank)
             self.done.extend(batch)
-            obs.counter("serve/requests_done").inc(len(batch))
             return len(batch)
 
     def drain(self, now: float) -> int:

@@ -27,6 +27,7 @@ import math
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import theory
 from repro.rp import ProjectorSpec
 
@@ -194,28 +195,42 @@ class SketchStore:
             raise ValueError(f"query of shape {q.shape} does not end in the "
                              f"store's k = {self.k}")
         q = q.astype(self._dtype, copy=False)
-        qj = jnp.asarray(q)
-        qn = np.einsum("bk,bk->b", q, q, dtype=np.float32)
         nb = q.shape[0]
-        best_d = np.full((nb, top_m), np.inf, np.float32)
-        best_i = np.full((nb, top_m), -1, np.int64)
-        for start in range(0, self._n, self.query_tile):
-            stop = min(start + self.query_tile, self._n)
-            tile = self._data[start:stop]
-            # ONE matmul per tile: (B, k) @ (k, tile) on the accelerator.
-            dots = np.asarray(jnp.matmul(qj, jnp.asarray(tile.T)),
-                              np.float32)
-            d2 = qn[:, None] - 2.0 * dots + self._norms2[start:stop][None]
-            cand_d = np.concatenate([best_d, d2], axis=1)
-            cand_i = np.concatenate(
-                [best_i, np.broadcast_to(np.arange(start, stop),
-                                         (nb, stop - start))], axis=1)
-            keep = np.argpartition(cand_d, top_m - 1, axis=1)[:, :top_m]
-            best_d = np.take_along_axis(cand_d, keep, axis=1)
-            best_i = np.take_along_axis(cand_i, keep, axis=1)
-        order = np.argsort(best_d, axis=1, kind="stable")
-        best_d = np.maximum(np.take_along_axis(best_d, order, axis=1), 0.0)
-        best_i = np.take_along_axis(best_i, order, axis=1)
+        with obs.span("store.query", rows=self._n,
+                      tiles=-(-self._n // self.query_tile), batch=nb,
+                      top_m=top_m):
+            qj = jnp.asarray(q)
+            qn = np.einsum("bk,bk->b", q, q, dtype=np.float32)
+            best_d = np.full((nb, top_m), np.inf, np.float32)
+            best_i = np.full((nb, top_m), -1, np.int64)
+            for start in range(0, self._n, self.query_tile):
+                stop = min(start + self.query_tile, self._n)
+                tile = self._data[start:stop]
+                # ONE matmul per tile: (B, k) @ (k, tile) on the accelerator.
+                with obs.span("store.upload"):
+                    up = jnp.asarray(tile.T)
+                with obs.span("store.fetch"):
+                    dots = np.asarray(jnp.matmul(qj, up), np.float32)
+                with obs.span("store.merge"):
+                    d2 = (qn[:, None] - 2.0 * dots
+                          + self._norms2[start:stop][None])
+                    cand_d = np.concatenate([best_d, d2], axis=1)
+                    cand_i = np.concatenate(
+                        [best_i, np.broadcast_to(np.arange(start, stop),
+                                                 (nb, stop - start))],
+                        axis=1)
+                    keep = np.argpartition(cand_d, top_m - 1,
+                                           axis=1)[:, :top_m]
+                    best_d = np.take_along_axis(cand_d, keep, axis=1)
+                    best_i = np.take_along_axis(cand_i, keep, axis=1)
+            # the bytes this call sent to the device: the queries and every
+            # stored row, once
+            obs.counter("store/h2d_bytes").inc(
+                q.nbytes + self._n * self.k * self._data.itemsize)
+            order = np.argsort(best_d, axis=1, kind="stable")
+            best_d = np.maximum(np.take_along_axis(best_d, order, axis=1),
+                                0.0)
+            best_i = np.take_along_axis(best_i, order, axis=1)
         if squeeze:
             best_d, best_i = best_d[0], best_i[0]
         delta = self.delta if delta is None else delta

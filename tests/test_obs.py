@@ -270,6 +270,19 @@ def test_disabled_fast_path_returns_shared_noops():
     assert obs.get_context() is None
 
 
+def test_enable_uses_the_tracer_and_registry_it_is_given():
+    tracer, metrics = Tracer(), MetricsRegistry()
+    assert len(tracer) == 0                              # empty, so falsy
+    ctx = obs.enable(tracer=tracer, metrics=metrics)
+    try:
+        assert ctx.tracer is tracer and ctx.metrics is metrics
+        with obs.span("x"):
+            pass
+        assert [e["name"] for e in tracer.events()] == ["x"]
+    finally:
+        obs.disable()
+
+
 def test_capture_exports_on_exit(tmp_path):
     tp, mp = tmp_path / "t.json", tmp_path / "m.jsonl"
     with obs.capture(trace_path=tp, metrics_path=mp):
@@ -497,6 +510,6 @@ def test_shared_timeline_serve_plus_train(tmp_path):
     rows = obs.read_jsonl(mp)
     assert any(r["type"] == "histogram" and r["name"] == "serve/queue_delay_us"
                for r in rows)
-    assert any(r["type"] == "counter" and r["name"] == "serve/requests_done"
-               for r in rows)
-    assert ctx.metrics.counter("serve/requests_done").value == 16
+    assert any(r["type"] == "histogram" and r["name"] == "serve/queue_delay_us"
+               and r["count"] == 16 for r in rows)
+    assert ctx.metrics.histogram("serve/queue_delay_us").count == 16

@@ -463,6 +463,47 @@ def test_query_tiling_is_transparent():
     np.testing.assert_allclose(ra.dist2, rb.dist2, rtol=1e-5, atol=1e-4)
 
 
+@pytest.mark.parametrize("nq", [1, 3])
+def test_query_answers_the_same_bitwise_with_obs_on_and_off(nq):
+    from repro import obs
+    store = SketchStore(SPEC, query_tile=10)
+    rng = np.random.default_rng(2)
+    store.add(rng.standard_normal((33, SPEC.k)).astype(np.float32))
+    q = rng.standard_normal((nq, SPEC.k)).astype(np.float32)
+    off = store.query(q, 5)
+    ctx = obs.enable()
+    try:
+        on = store.query(q, 5)
+    finally:
+        obs.disable()
+    np.testing.assert_array_equal(on.ids, off.ids)
+    assert on.dist2.tobytes() == off.dist2.tobytes()
+    # the queries and each of the 33 stored rows went to the device once
+    assert ctx.metrics.counter("store/h2d_bytes").value == \
+        4 * SPEC.k * (nq + 33)
+
+
+def test_query_spans_nest_one_set_per_tile_under_the_query():
+    from repro import obs
+    store = SketchStore(SPEC, query_tile=10)
+    store.add(np.random.default_rng(3).standard_normal(
+        (33, SPEC.k)).astype(np.float32))
+    with obs.capture() as ctx:
+        store.query(store.get([0, 1]), 3)
+    evs = sorted(ctx.tracer.to_chrome()["traceEvents"],
+                 key=lambda e: e["ts"])
+    (top,) = [e for e in evs if e["name"] == "store.query"]
+    assert top["args"] == {"rows": 33, "tiles": 4, "batch": 2, "top_m": 3}
+    inner = [e for e in evs if e is not top]
+    assert [e["name"] for e in inner] == \
+        ["store.upload", "store.fetch", "store.merge"] * 4
+    for e in inner:
+        assert e["args"] == {"depth": 1}          # no attributes of its own
+        assert e["tid"] == top["tid"]
+        assert top["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= top["ts"] + top["dur"]
+
+
 def test_pairwise_matches_stored_sketch_distances():
     store = SketchStore(SPEC)
     rng = np.random.default_rng(1)
