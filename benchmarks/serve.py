@@ -10,7 +10,7 @@ Three row families, all seeded and deterministic in structure:
   serve/cache       — operator-cache hit vs regeneration cost on a
       repeated-spec trace (hit rate is an acceptance criterion: >= 0.9).
   serve/query       — the brute-force-but-batched top-m similarity sweep
-      over a large sketch store (one matmul tile sweep per query batch).
+      over a large sketch store (one device program per query batch).
 """
 import time
 

@@ -34,9 +34,11 @@ Wired call sites (all behind the disabled fast path):
                        order, backend, pipeline) + the launch breakdown
   serve.engine       — per-tick spans, queue-delay histograms, distortion
                        feed for dense payloads
-  serve.store        — per-query `store.query` span with per-tile
-                       `store.upload`/`store.fetch`/`store.merge` spans
-                       nested in it; the `store/h2d_bytes` counter
+  serve.store        — per-query `store.query` span with the query
+                       program's dispatch (`store.sweep`) and the copy
+                       back of its answer (`store.fetch`) nested in it;
+                       the counters `store/h2d_bytes` (bytes sent to the
+                       device) and `store/grows` (device reallocations)
   runtime.train_loop — per-step spans; straggler/resume/fallback events
   ckpt.checkpointer  — save/verify/restore spans (async saves on their
                        own thread track) + fallback events

@@ -14,9 +14,10 @@ that workload as a serving layer on top of the kernel/dispatch stack:
   * `OperatorCache`   — LRU keyed on (ProjectorSpec, seed); operators are a
     seed plus shapes, so a hit means zero regeneration (hit/miss/regen
     stats included).
-  * `SketchStore`     — millions of stored k-vectors; brute-force-but-
-    batched top-m retrieval via a matmul tile sweep, plus a pairwise
-    endpoint, every answer carrying the Thm-1 distortion bound.
+  * `SketchStore`     — millions of stored k-vectors held on the device;
+    brute-force-but-batched top-m retrieval as one device program per
+    query, plus a pairwise endpoint, every answer carrying the Thm-1
+    distortion bound.
   * `SketchServer`    — the engine tying the above together (clock-explicit
     and deterministic; an async transport goes on top).
   * `synth_trace` / `replay` — the offline load generator reporting

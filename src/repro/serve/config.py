@@ -28,8 +28,11 @@ class ServeConfig:
     backend        : `repro.rp` backend policy for the per-tick dispatch.
     ingest         : add completed sketches (of the store's own spec) to
                      the sketch store so they become retrievable.
-    query_tile     : stored-sketch rows per matmul tile of the similarity
-                     sweep (bounds the (B, tile) distance intermediate).
+    query_tile     : stored-sketch rows per group of the query program's
+                     two-stage top-m: the `top_m` groups with the smallest
+                     minima are chosen first, then the top m of their
+                     rows (the second stage reads `top_m * query_tile`
+                     candidates per query row).
     delta          : default failure probability of the Thm-1/Chebyshev
                      distortion bound reported next to query results.
     stats_window   : completed requests the latency percentiles in

@@ -101,7 +101,7 @@ class SketchServer:
             self.occupancy.append(len(batch) / self.cfg.max_batch)
             ingest = (self.store is not None and self.cfg.ingest
                       and key.spec == self.store.spec)
-            ids = self.store.add(np.asarray(ys)) if ingest else None
+            ids = self.store.add(ys) if ingest else None
             delay_hist = obs.histogram("serve/queue_delay_us")
             for i, req in enumerate(batch):
                 req.sketch = ys[i]
@@ -139,7 +139,7 @@ class SketchServer:
         return served
 
     # -- retrieval (straight to the store; no batching needed: a query is
-    # -- one tiled matmul sweep, not a kernel dispatch) -------------------
+    # -- one device program over the resident store) ----------------------
     def query(self, q, top_m: int, *, delta: float | None = None
               ) -> QueryResult:
         if self.store is None:

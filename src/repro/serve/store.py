@@ -13,19 +13,25 @@ sketches estimates the true squared distance to relative error
 alongside every result. Nearest-neighbor and pairwise-similarity queries
 therefore never touch the original (possibly d^N-sized) inputs.
 
-Retrieval is brute-force-but-batched: one `(B, k) @ (k, tile)` matmul per
-tile of the store sweeps all n stored sketches (`query_tile` rows at a
-time, bounding the distance intermediate), with a running top-m merge on
-the host between tiles — the classic memory/recall-free baseline that JL
+Retrieval is brute-force-but-batched and stays on the device: the stored
+rows and their squared norms live in device memory, and each query is ONE
+jitted program — a float32 `(B, k) @ (k, capacity)` product over every
+stored row, then an exact two-stage top-m (the `top_m` groups of
+`query_tile` rows with the smallest minima, then the top m of their rows)
+— so a query sends only its own `(B, k)` rows to the chip and copies back
+only the `(B, m)` answer. The classic memory/recall-free baseline that JL
 embeddings make cheap enough to serve millions of vectors.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import obs
 from repro.core import theory
@@ -82,14 +88,70 @@ class PairwiseResult:
         return self.dist2 / (1.0 - self.eps)
 
 
+def _sq_norms(x):
+    """Row-wise squared norms, summed in float32 on the device."""
+    x = x.astype(jnp.float32)
+    return jnp.sum(x * x, axis=-1)
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def _grown(data, norms2, cap):
+    """The store's buffers copied into room for `cap` rows (zero-padded)."""
+    pad = cap - data.shape[0]
+    return jnp.pad(data, ((0, pad), (0, 0))), jnp.pad(norms2, (0, pad))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+def _append(data, norms2, n, rows):
+    """Rows written at `n` in place (the buffers are donated), with their
+    squared norms; returns the buffers and the new row count."""
+    data = lax.dynamic_update_slice(data, rows, (n, 0))
+    norms2 = lax.dynamic_update_slice(norms2, _sq_norms(rows), (n,))
+    return data, norms2, n + rows.shape[0]
+
+
+@functools.partial(jax.jit, static_argnames=("top_m", "tile"))
+def _nearest(data, norms2, n, q, *, top_m, tile):
+    """Exact top-m of `||q||^2 - 2 q.x + ||x||^2` over the first `n` rows.
+
+    Stage 1 takes the `top_m` groups of `tile` consecutive rows whose
+    smallest distance is least; stage 2 the top m of those groups' rows.
+    Exact: each chosen group holds a distance no larger than any unchosen
+    group's minimum, so the m least distances all lie in the chosen
+    groups. Rows at or past `n` read +inf and are never chosen while
+    `top_m <= n`."""
+    b, cap = q.shape[0], data.shape[0]
+    dots = jnp.einsum("bk,nk->bn", q, data,
+                      precision=lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+    d2 = _sq_norms(q)[:, None] - 2.0 * dots + norms2[None]
+    d2 = jnp.where(jnp.arange(cap) < n, d2, jnp.inf)
+    groups = -(-cap // tile)
+    d2 = jnp.pad(d2, ((0, 0), (0, groups * tile - cap)),
+                 constant_values=jnp.inf).reshape(b, groups, tile)
+    _, gids = lax.top_k(-jnp.min(d2, axis=2), min(top_m, groups))
+    cand = jnp.take_along_axis(d2, gids[:, :, None], axis=1).reshape(b, -1)
+    neg, pos = lax.top_k(-cand, top_m)
+    ids = jnp.take_along_axis(gids, pos // tile, axis=1) * tile + pos % tile
+    return ids, jnp.maximum(-neg, 0.0)
+
+
+@jax.jit
+def _pair_dist2(data, ids_a, ids_b):
+    diff = (data[ids_a].astype(jnp.float32)
+            - data[ids_b].astype(jnp.float32))
+    return jnp.sum(diff * diff, axis=-1)
+
+
 class SketchStore:
     """Append-only store of `(k,)` sketches from ONE projector spec.
 
     One spec per store, on purpose: sketches from different operators live
     in unrelated embeddings and their mutual distances are meaningless —
-    the serving engine keys ingestion on the store's spec. Rows are held in
-    a growable (doubling) host array; matmul tiles move to the accelerator
-    per sweep step.
+    the serving engine keys ingestion on the store's spec. Rows and their
+    squared norms are held in device arrays that grow by doubling from
+    1024 rows (each growth one copy of the store in device memory, counted
+    by `store/grows`); `add` writes into them in place.
     """
 
     def __init__(self, spec: ProjectorSpec, *, query_tile: int = 4096,
@@ -106,8 +168,12 @@ class SketchStore:
         # the c in eps = sqrt(c / (k delta)).
         self.var_factor = theory.variance_factor(
             spec.family, N=len(spec.dims), R=spec.rank, D=spec.input_size)
-        self._data = np.empty((0, self.k), np.float32)
-        self._norms2 = np.empty((0,), np.float32)
+        # device buffers, allocated by the first ingest (which fixes the
+        # dtype); `_count` is the row count kept on the device, so neither
+        # `add` nor `query` sends it
+        self._data = None
+        self._norms2 = None
+        self._count = None
         self._n = 0
         self._dtype: np.dtype | None = None
 
@@ -116,7 +182,9 @@ class SketchStore:
 
     def nbytes(self) -> int:
         """Resident sketch bytes (the 'millions of users' memory axis)."""
-        return self._n * self.k * self._data.itemsize
+        if self._data is None:
+            return 0
+        return self._n * self.k * self._data.dtype.itemsize
 
     def eps_bound(self, delta: float | None = None) -> float:
         """Thm-1/Chebyshev relative error of squared distances at `delta`."""
@@ -129,12 +197,16 @@ class SketchStore:
     def add(self, sketches) -> np.ndarray:
         """Append `(B, k)` (or a single `(k,)`) sketches; returns their ids.
 
+        `sketches` is a host array or a device array; a device array is
+        written on the device and never copied through the host.
+
         The store's element dtype is fixed by the FIRST ingest; mixing
         dtypes afterwards is a typed error — silently upcasting would make
         stored distances incomparable across rows (and hide a producer
         regression), exactly the misuse the serve config errors guard.
         """
-        arr = np.asarray(sketches)
+        on_device = isinstance(sketches, jax.Array)
+        arr = sketches if on_device else np.asarray(sketches)
         if arr.ndim == 1:
             arr = arr[None]
         if arr.ndim != 2 or arr.shape[1] != self.k:
@@ -144,31 +216,45 @@ class SketchStore:
         dt = np.dtype(arr.dtype)
         if self._dtype is None:
             self._dtype = dt
-            self._data = self._data.astype(dt)
         elif dt != self._dtype:
             raise ValueError(
                 f"mixed-dtype ingest: store holds {self._dtype.name} "
                 f"sketches, got {dt.name}; re-sketch with a consistent "
                 "dtype (one spec, one dtype per store)")
         b = arr.shape[0]
-        if self._n + b > self._data.shape[0]:
-            cap = max(2 * self._data.shape[0], self._n + b, 1024)
-            grown = np.empty((cap, self.k), self._dtype)
-            grown[:self._n] = self._data[:self._n]
-            self._data = grown
-            grown_n = np.empty((cap,), np.float32)
-            grown_n[:self._n] = self._norms2[:self._n]
-            self._norms2 = grown_n
+        cap = 0 if self._data is None else self._data.shape[0]
+        if self._n + b > cap:
+            # room first, the upload after it: the peak is then the old and
+            # the new buffers, not those and the new rows besides
+            grown = max(2 * cap, self._n + b, 1024)
+            if self._data is None:
+                self._data = jnp.zeros((grown, self.k),
+                                       jax.dtypes.canonicalize_dtype(dt))
+                self._norms2 = jnp.zeros((grown,), jnp.float32)
+                self._count = jnp.zeros((), jnp.int32)
+            else:
+                obs.counter("store/grows").inc()
+                self._data, self._norms2 = _grown(self._data, self._norms2,
+                                                  grown)
+        if not on_device:
+            arr = jnp.asarray(arr)
+            obs.counter("store/h2d_bytes").inc(arr.nbytes)
+        self._data, self._norms2, self._count = _append(
+            self._data, self._norms2, self._count, arr)
         ids = np.arange(self._n, self._n + b)
-        self._data[self._n:self._n + b] = arr
-        self._norms2[self._n:self._n + b] = np.einsum(
-            "bk,bk->b", arr, arr, dtype=np.float32)
         self._n += b
         return ids
 
+    def _ids(self, ids) -> np.ndarray:
+        ids = np.asarray(ids)
+        if ids.size and (ids.min() < 0 or ids.max() >= self._n):
+            raise ValueError(f"sketch id out of range [0, {self._n})")
+        obs.counter("store/h2d_bytes").inc(ids.nbytes)
+        return ids
+
     def get(self, ids) -> np.ndarray:
-        """Stored sketches by id (view into the store)."""
-        return self._data[:self._n][np.asarray(ids)]
+        """Stored sketches by id, gathered on the device (a host copy)."""
+        return np.asarray(self._data[self._ids(ids)])
 
     # -- retrieval -------------------------------------------------------
     def query(self, q, top_m: int, *, delta: float | None = None
@@ -179,6 +265,9 @@ class SketchStore:
         top_m : results per query; must satisfy 1 <= top_m <= len(store)
                 (a typed error otherwise — asking for more neighbors than
                 the store holds is a caller bug, not a clamp).
+
+        One program per (B, capacity, top_m, query_tile, dtype): an `add`
+        that does not grow the store reuses it.
         """
         if self._n == 0:
             raise ValueError("query on an empty store; ingest sketches "
@@ -195,42 +284,17 @@ class SketchStore:
             raise ValueError(f"query of shape {q.shape} does not end in the "
                              f"store's k = {self.k}")
         q = q.astype(self._dtype, copy=False)
-        nb = q.shape[0]
         with obs.span("store.query", rows=self._n,
-                      tiles=-(-self._n // self.query_tile), batch=nb,
+                      tiles=-(-self._n // self.query_tile), batch=q.shape[0],
                       top_m=top_m):
-            qj = jnp.asarray(q)
-            qn = np.einsum("bk,bk->b", q, q, dtype=np.float32)
-            best_d = np.full((nb, top_m), np.inf, np.float32)
-            best_i = np.full((nb, top_m), -1, np.int64)
-            for start in range(0, self._n, self.query_tile):
-                stop = min(start + self.query_tile, self._n)
-                tile = self._data[start:stop]
-                # ONE matmul per tile: (B, k) @ (k, tile) on the accelerator.
-                with obs.span("store.upload"):
-                    up = jnp.asarray(tile.T)
-                with obs.span("store.fetch"):
-                    dots = np.asarray(jnp.matmul(qj, up), np.float32)
-                with obs.span("store.merge"):
-                    d2 = (qn[:, None] - 2.0 * dots
-                          + self._norms2[start:stop][None])
-                    cand_d = np.concatenate([best_d, d2], axis=1)
-                    cand_i = np.concatenate(
-                        [best_i, np.broadcast_to(np.arange(start, stop),
-                                                 (nb, stop - start))],
-                        axis=1)
-                    keep = np.argpartition(cand_d, top_m - 1,
-                                           axis=1)[:, :top_m]
-                    best_d = np.take_along_axis(cand_d, keep, axis=1)
-                    best_i = np.take_along_axis(cand_i, keep, axis=1)
-            # the bytes this call sent to the device: the queries and every
-            # stored row, once
-            obs.counter("store/h2d_bytes").inc(
-                q.nbytes + self._n * self.k * self._data.itemsize)
-            order = np.argsort(best_d, axis=1, kind="stable")
-            best_d = np.maximum(np.take_along_axis(best_d, order, axis=1),
-                                0.0)
-            best_i = np.take_along_axis(best_i, order, axis=1)
+            with obs.span("store.sweep"):
+                out = _nearest(self._data, self._norms2, self._count, q,
+                               top_m=top_m, tile=self.query_tile)
+            with obs.span("store.fetch"):
+                best_i, best_d = jax.device_get(out)
+            # the bytes this call sent to the device: the queries alone
+            obs.counter("store/h2d_bytes").inc(q.nbytes)
+        best_i = best_i.astype(np.int64)
         if squeeze:
             best_d, best_i = best_d[0], best_i[0]
         delta = self.delta if delta is None else delta
@@ -245,14 +309,8 @@ class SketchStore:
         estimates the true squared distance of the ORIGINAL inputs to
         relative error `eps` (per pair, failure probability <= delta).
         """
-        ids_a = np.asarray(ids_a)
-        ids_b = np.asarray(ids_b)
-        for ids in (ids_a, ids_b):
-            if ids.size and (ids.min() < 0 or ids.max() >= self._n):
-                raise ValueError(f"sketch id out of range [0, {self._n})")
-        diff = (self._data[:self._n][ids_a].astype(np.float32)
-                - self._data[:self._n][ids_b].astype(np.float32))
-        d2 = np.einsum("...k,...k->...", diff, diff)
+        d2 = np.asarray(_pair_dist2(self._data, self._ids(ids_a),
+                                    self._ids(ids_b)))
         delta = self.delta if delta is None else delta
         return PairwiseResult(dist2=d2, eps=self.eps_bound(delta),
                               delta=delta)

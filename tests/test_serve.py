@@ -478,30 +478,139 @@ def test_query_answers_the_same_bitwise_with_obs_on_and_off(nq):
         obs.disable()
     np.testing.assert_array_equal(on.ids, off.ids)
     assert on.dist2.tobytes() == off.dist2.tobytes()
-    # the queries and each of the 33 stored rows went to the device once
-    assert ctx.metrics.counter("store/h2d_bytes").value == \
-        4 * SPEC.k * (nq + 33)
+    # the rows stay on the device: the query sent its own rows alone
+    assert ctx.metrics.counter("store/h2d_bytes").value == 4 * SPEC.k * nq
 
 
 def test_query_spans_nest_one_set_per_tile_under_the_query():
+    """One query, one device program: `store.query` holds the program's
+    dispatch (`store.sweep`) and the copy back of its answer
+    (`store.fetch`), in that order, however many tiles the rows span."""
     from repro import obs
     store = SketchStore(SPEC, query_tile=10)
     store.add(np.random.default_rng(3).standard_normal(
         (33, SPEC.k)).astype(np.float32))
+    q = store.get([0, 1])
     with obs.capture() as ctx:
-        store.query(store.get([0, 1]), 3)
+        store.query(q, 3)
     evs = sorted(ctx.tracer.to_chrome()["traceEvents"],
                  key=lambda e: e["ts"])
     (top,) = [e for e in evs if e["name"] == "store.query"]
     assert top["args"] == {"rows": 33, "tiles": 4, "batch": 2, "top_m": 3}
     inner = [e for e in evs if e is not top]
-    assert [e["name"] for e in inner] == \
-        ["store.upload", "store.fetch", "store.merge"] * 4
+    assert [e["name"] for e in inner] == ["store.sweep", "store.fetch"]
     for e in inner:
         assert e["args"] == {"depth": 1}          # no attributes of its own
         assert e["tid"] == top["tid"]
         assert top["ts"] <= e["ts"]
         assert e["ts"] + e["dur"] <= top["ts"] + top["dur"]
+    assert inner[0]["ts"] + inner[0]["dur"] <= inner[1]["ts"]
+
+
+def _brute_top(rows, q, m):
+    """Float64 brute force: ids of the m least squared distances, and
+    those distances, per query row."""
+    d2 = ((q.astype(np.float64)[:, None] - rows.astype(np.float64)[None])
+          ** 2).sum(-1)
+    ids = np.argsort(d2, axis=1, kind="stable")[:, :m]
+    return ids, np.take_along_axis(d2, ids, axis=1)
+
+
+@pytest.mark.parametrize("tile", [7, 64, 4096])
+def test_device_query_matches_float64_brute_force_after_growth(tile):
+    """Rows that fill no whole tile, in a store that grew several times:
+    the device program's top-m is the float64 brute force's."""
+    from repro import obs
+    store = SketchStore(SPEC, query_tile=tile)
+    rng = np.random.default_rng(11)
+    # JL sketches of unit-norm items: rows of norm ~1, so float32's
+    # rounding of ||q||^2 - 2 q.x + ||x||^2 stays near 1e-7
+    rows = (rng.standard_normal((2500, SPEC.k))
+            / np.sqrt(SPEC.k)).astype(np.float32)
+    with obs.capture() as ctx:
+        for lo, hi in [(0, 700), (700, 1100), (1100, 2100), (2100, 2500)]:
+            store.add(rows[lo:hi])                # 1024 -> 2048 -> 4096
+    assert ctx.metrics.counter("store/grows").value == 2
+    assert 2500 % tile
+    q = rows[[3, 1500, 2499]] + np.float32(0.01) * rng.standard_normal(
+        (3, SPEC.k)).astype(np.float32)
+    res = store.query(q, 9)
+    want_ids, want_d2 = _brute_top(rows, q, 9)
+    np.testing.assert_array_equal(res.ids, want_ids)
+    np.testing.assert_allclose(res.dist2, want_d2, rtol=1e-5, atol=1e-6)
+    assert res.ids[:, 0].tolist() == [3, 1500, 2499]
+
+
+@pytest.mark.parametrize("tile", [3, 10, 4096])
+def test_query_for_every_row_returns_each_id_once_and_no_empty_row(tile):
+    """`top_m == len(store)`: every stored id comes back once, in
+    ascending distance, and no row past the last stored one (the
+    capacity's padding reads +inf) is ever returned."""
+    store = SketchStore(SPEC, query_tile=tile)
+    rng = np.random.default_rng(12)
+    rows = (rng.standard_normal((33, SPEC.k))
+            / np.sqrt(SPEC.k)).astype(np.float32)
+    store.add(rows)
+    q = (rng.standard_normal((2, SPEC.k)) / np.sqrt(SPEC.k)).astype(
+        np.float32)
+    res = store.query(q, 33)
+    for i in range(2):
+        assert sorted(res.ids[i].tolist()) == list(range(33))
+        assert np.all(np.diff(res.dist2[i]) >= 0)
+        assert np.all(np.isfinite(res.dist2[i]))
+    want_ids, want_d2 = _brute_top(rows, q, 33)
+    np.testing.assert_array_equal(res.ids, want_ids)
+    np.testing.assert_allclose(res.dist2, want_d2, rtol=1e-5, atol=1e-6)
+
+
+def test_add_after_a_query_is_retrievable_without_a_new_query_program():
+    """The live row count is an input of the query program, not a shape:
+    rows added within the capacity are found by the program already
+    compiled."""
+    from repro.serve import store as store_mod
+    store = SketchStore(SPEC, query_tile=10)
+    rng = np.random.default_rng(13)
+    store.add(rng.standard_normal((40, SPEC.k)).astype(np.float32))
+    q = rng.standard_normal((1, SPEC.k)).astype(np.float32)
+    store.query(q, 5)
+    compiled = store_mod._nearest._cache_size()
+    new = rng.standard_normal((25, SPEC.k)).astype(np.float32)
+    ids = store.add(new)
+    assert ids.tolist() == list(range(40, 65))
+    for j in (0, 24):
+        res = store.query(new[j], 5)
+        assert res.ids[0] == 40 + j and res.dist2[0] < 1e-4
+    assert store.query(q, 5).ids.shape == (1, 5)
+    assert store_mod._nearest._cache_size() == compiled
+    np.testing.assert_array_equal(store.get([40, 64]), new[[0, 24]])
+
+
+def test_device_rows_cross_no_bytes_and_each_doubling_counts():
+    """A device array is written on the device: `store/h2d_bytes` counts
+    host rows alone. `store/grows` counts each reallocation of a store
+    that held rows (the first allocation copies nothing)."""
+    from repro import obs
+    rng = np.random.default_rng(14)
+    host = rng.standard_normal((1024, SPEC.k)).astype(np.float32)
+    store = SketchStore(SPEC)
+    with obs.capture() as ctx:
+        store.add(jnp.asarray(host))              # allocates 1024 rows
+        assert ctx.metrics.counter("store/h2d_bytes").value == 0
+        assert ctx.metrics.counter("store/grows").value == 0
+        store.add(jnp.asarray(host[:1]))          # 1024 -> 2048
+        store.add(jnp.asarray(host[1:]))          # fits
+        assert ctx.metrics.counter("store/grows").value == 1
+        store.add(jnp.asarray(host[:2]))          # 2048 -> 4096
+        assert ctx.metrics.counter("store/grows").value == 2
+        assert ctx.metrics.counter("store/h2d_bytes").value == 0
+        store.add(host[:3])                       # host rows are sent
+        assert ctx.metrics.counter("store/h2d_bytes").value == \
+            3 * SPEC.k * 4
+    assert len(store) == 2053
+    np.testing.assert_array_equal(store.get([0, 1024, 2050, 2052]),
+                                  host[[0, 0, 0, 2]])
+    with pytest.raises(ValueError, match="mixed-dtype"):
+        store.add(jnp.asarray(host[:1], jnp.bfloat16))
 
 
 def test_pairwise_matches_stored_sketch_distances():
